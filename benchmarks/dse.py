@@ -69,6 +69,9 @@ import sys
 import time
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# JAX's persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset: a
+# fixed path in the checkout, so a later run finds it again (.gitignore'd)
+COMPILE_CACHE_DIR = os.path.join(_ROOT, ".jax_cache")
 for p in (_ROOT, os.path.join(_ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
@@ -379,16 +382,25 @@ def main(argv=None) -> int:
         enable_tracing()
 
     t0 = time.perf_counter()
-    # provenance stamp: which engine scored this sweep, under which jax —
-    # so perf trajectories across PRs/artifacts stay attributable.  jax is
-    # only probed when actually requested: plain NumPy sweeps (and their
-    # worker processes) must stay jax-free.
-    jax_version = None
+    if args.engine == "jax" and args.workers > 1:
+        ap.error("--engine jax scores in one process (worker processes "
+                 "cannot share the parent's device); drop --workers")
+    # provenance stamp: which engine scored this sweep, under which jax, on
+    # which device — so perf trajectories across PRs/artifacts stay
+    # attributable.  jax is only probed when actually requested: plain
+    # NumPy sweeps (and their worker processes) must stay jax-free.
+    jax_version = device = None
     if args.engine == "jax" or args.engine_bench or args.design_batch:
-        from repro.core.perf_model_jax import jax_available
+        from repro.core.perf_model_jax import (device_record, jax_available,
+                                               use_compile_cache)
         if jax_available():
             import jax as _jax_mod
             jax_version = _jax_mod.__version__
+            cache_dir = use_compile_cache(COMPILE_CACHE_DIR)
+            device = device_record()
+            print(f"  jax {jax_version} on {device['count']}x "
+                  f"{device['platform']} ({device['kind']}); compile cache "
+                  f"{cache_dir}")
         elif args.engine == "jax":
             ap.error("--engine jax: the jax runtime is not importable in "
                      "this environment; use --engine numpy")
@@ -569,7 +581,7 @@ def main(argv=None) -> int:
             "faults": plan.spec() if plan.active else None}
     from repro.obs import provenance_record
     provenance = provenance_record(
-        extra={"engine": args.engine, "jax": jax_version,
+        extra={"engine": args.engine, "jax": jax_version, "device": device,
                "strategy": args.strategy, "seed": args.seed,
                "budget": args.budget,
                "design_batch": bool(args.design_batch)})

@@ -15,7 +15,7 @@ Contract with the NumPy engine (the differential-testing harness in
 
 * every integer-derived quantity (cycles, MACs, utilization, DRAM bytes,
   SRAM reads, PPU cycles, the memory-bound flag) is **bit-identical** —
-  all reductions (``prod``/``cumprod``/``einsum``) run in int64 exactly
+  all reductions (``prod``/``cumprod``/``sum``) run in int64 exactly
   like NumPy, and the float steps are elementwise IEEE ops;
 * ``energy_pj`` may differ by float-associativity noise (XLA is free to
   contract multiply-adds into FMAs), bounded by :data:`ENERGY_RTOL`;
@@ -29,13 +29,14 @@ Contract with the NumPy engine (the differential-testing harness in
 JAX is imported lazily and only on first use: DSE worker processes stay
 NumPy-only unless ``engine="jax"`` is actually requested, and environments
 without jax degrade to a clear error (guard with :func:`jax_available`).
-float64 semantics come from the ``jax.experimental.enable_x64`` scoped
-override, not the global flag, so co-resident float32 Pallas kernels keep
+float64 semantics come from the ``jax.enable_x64(True)`` scoped override,
+not the global flag, so co-resident float32 Pallas kernels keep
 their dtypes.
 """
 
 from __future__ import annotations
 
+import os
 import time
 
 import numpy as np
@@ -47,7 +48,8 @@ from .perf_model import HWConfig
 from .workload import Workload
 
 __all__ = ["jax_available", "perf_kernel_jax", "perf_kernel_jax_design",
-           "ENERGY_RTOL", "clear_compile_cache", "ENGINES"]
+           "design_kernel_program", "ENERGY_RTOL", "clear_compile_cache",
+           "use_compile_cache", "device_record", "ENGINES"]
 
 # the engines a mapping query can be solved with ("numpy" is the batched
 # default; "batch" is its historical alias; "scalar" is the reference
@@ -89,6 +91,31 @@ def _require_jax():
     return jax
 
 
+def use_compile_cache(default_dir: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    stands; otherwise the cache goes to ``default_dir``, a fixed path (the
+    path is part of what a later run must find again).  Every compile is
+    cached, however short, so the AOT kernel compiles of a repeated sweep
+    are read back instead of rebuilt.  Returns the directory in use.
+    Called from entry points only, never on import of a library module.
+    """
+    jax = _require_jax()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", default_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
+
+
+def device_record() -> dict:
+    """The device the JAX engine dispatches to, as JAX reports it."""
+    jax = _require_jax()
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def clear_compile_cache() -> None:
     """Drop all AOT-compiled kernels (tests / memory pressure)."""
     _COMPILED.clear()
@@ -115,7 +142,9 @@ def _candidate_kernel(jax, Mpos_list, b_list, dep_list, out_mask, L, D):
     Mirrors ``extents_kernel → footprint_kernel → traffic_kernel →
     perf_kernel`` from :mod:`repro.core.perf_model` for one candidate row;
     every reduction stays in int64 so the integer-derived outputs are
-    bit-identical to the NumPy engine.
+    bit-identical to the NumPy engine.  No reduction is a ``dot``: the
+    footprint contraction is an elementwise product and a sum, because the
+    TPU compiler refuses 64-bit ``dot_general``.
     """
     jnp = jax.numpy
     T = len(Mpos_list)
@@ -150,7 +179,9 @@ def _candidate_kernel(jax, Mpos_list, b_list, dep_list, out_mask, L, D):
         for k in range(T):
             Mpos = jnp.asarray(Mpos_list[k])
             bvec = jnp.asarray(b_list[k])
-            mx = jnp.einsum("rd,ld->lr", Mpos, E - 1) + bvec
+            # broadcast multiply + integer sum, not an int64 dot_general:
+            # the TPU compiler cannot rewrite an s64 dot into 32-bit ops
+            mx = (Mpos[None, :, :] * (E - 1)[:, None, :]).sum(axis=2) + bvec
             fp = jnp.prod(mx + 1, axis=1).astype(jnp.float64) * db[k]
             fits = fp <= budget[k]
             lvl = jnp.where(fits.any(), jnp.argmax(fits), L)
@@ -184,6 +215,17 @@ def _candidate_kernel(jax, Mpos_list, b_list, dep_list, out_mask, L, D):
     return kernel
 
 
+def _workload_kernel(jax, wl: Workload, L: int):
+    """:func:`_candidate_kernel` over ``wl``'s static tensor structure."""
+    return _candidate_kernel(
+        jax,
+        [np.clip(t.fmap.M, 0, None).astype(np.int64) for t in wl.tensors],
+        [np.asarray(t.fmap.b, dtype=np.int64) for t in wl.tensors],
+        [t.fmap.M.any(axis=0) for t in wl.tensors],
+        [t.role == "output" for t in wl.tensors],
+        L, len(wl.iter_dims))
+
+
 def _compiled_kernel(jax, wl: Workload, C: int, L: int):
     """AOT-compiled vmapped kernel for (workload structure, padded shapes).
 
@@ -200,14 +242,7 @@ def _compiled_kernel(jax, wl: Workload, C: int, L: int):
     if fn is not None:
         return fn
 
-    Mpos_list = [np.clip(t.fmap.M, 0, None).astype(np.int64)
-                 for t in wl.tensors]
-    b_list = [np.asarray(t.fmap.b, dtype=np.int64) for t in wl.tensors]
-    dep_list = [t.fmap.M.any(axis=0) for t in wl.tensors]
-    out_mask = [t.role == "output" for t in wl.tensors]
-
-    kernel = _candidate_kernel(jax, Mpos_list, b_list, dep_list, out_mask,
-                               L, D)
+    kernel = _workload_kernel(jax, wl, L)
     # vmap over the candidate axis; HW scalars/vectors broadcast (None)
     batched = jax.vmap(kernel,
                        in_axes=(0, 0, 0, 0, 0, 0, None, 0,
@@ -227,8 +262,7 @@ def _compiled_kernel(jax, wl: Workload, C: int, L: int):
     t0 = time.perf_counter()
     with span("mapper_batch.jax_compile", cat="mapper", workload=wl.name,
               candidates=C, loops=L):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             fn = jax.jit(batched).lower(*shapes).compile()
     METRICS.counter("mapper_batch.jax_compiles").inc()
     METRICS.histogram("mapper_batch.jax_compile_s").observe(
@@ -306,9 +340,8 @@ def perf_kernel_jax(
         np.float64(hw.data_bytes),
     )
     t0 = time.perf_counter()
-    from jax.experimental import enable_x64
     with span("mapper_batch.jax_execute", cat="mapper", workload=wl.name,
-              candidates=C), enable_x64():
+              candidates=C), jax.enable_x64(True):
         out = fn(*args)
         out = {k: np.asarray(v) for k, v in out.items()}
     METRICS.counter("mapper_batch.jax_dispatches").inc()
@@ -322,36 +355,26 @@ def perf_kernel_jax(
 # design axis: one dispatch scores D design points × C candidates
 # ---------------------------------------------------------------------------
 
-def _compiled_design_kernel(jax, wl: Workload, Dp: int, C: int, L: int):
-    """AOT-compiled ``(design, candidate)`` double-vmapped kernel.
+def design_kernel_program(jax, wl: Workload, Dp: int, C: int, L: int,
+                          sharding=None):
+    """The jitted ``(design, candidate)`` kernel and its argument shapes.
+
+    Returns ``(jitted, shapes)``: ``jitted.lower(*shapes).compile()`` under
+    ``jax.enable_x64(True)`` is the program :func:`_compiled_design_kernel`
+    dispatches.  ``sharding`` is placed on every ``ShapeDtypeStruct`` (None:
+    the default device), so the same program can be compiled for a
+    described, unattached device.
 
     The outer vmap runs over the design axis with ``in_axes=None`` for every
     candidate array, so the design-invariant chain — extents, footprints,
     compute cycles, true MACs — is traced **once** at ``(C, …)`` shape and
     shared by all D designs; only the footprint-vs-budget selection and the
     energy arithmetic batch to ``(D, C)``.  That work sharing (not
-    parallelism) is where the design-batched sweep speedup comes from, which
-    matters on single-core hosts where XLA cannot fan out threads.
-
-    The cache key is ``(workload, "design", Dp, Cp, Lp)``; HW parameters are
-    runtime arguments exactly as in :func:`_compiled_kernel`, so one compile
-    serves every tile of a sweep that reuses the same bucketed shape.
+    parallelism) is where the design-batched sweep speedup comes from.
     """
     D = len(wl.iter_dims)
     T = len(wl.tensors)
-    key = (wl.name, "design", Dp, C, L)
-    fn = _COMPILED.get(key)
-    if fn is not None:
-        return fn
-
-    Mpos_list = [np.clip(t.fmap.M, 0, None).astype(np.int64)
-                 for t in wl.tensors]
-    b_list = [np.asarray(t.fmap.b, dtype=np.int64) for t in wl.tensors]
-    dep_list = [t.fmap.M.any(axis=0) for t in wl.tensors]
-    out_mask = [t.role == "output" for t in wl.tensors]
-
-    kernel = _candidate_kernel(jax, Mpos_list, b_list, dep_list, out_mask,
-                               L, D)
+    kernel = _workload_kernel(jax, wl, L)
     per_design = jax.vmap(kernel,
                           in_axes=(0, 0, 0, 0, 0, 0, None, 0,
                                    None, None, None, None, None, None, None,
@@ -362,22 +385,38 @@ def _compiled_design_kernel(jax, wl: Workload, Dp: int, C: int, L: int):
                        in_axes=(None, None, None, None, None, None, 0, None,
                                 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
 
-    sds = jax.ShapeDtypeStruct
-    f64 = np.dtype(np.float64)
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=sharding)
+
+    i64, f64 = np.int64, np.float64
     shapes = (
-        sds((C, L), np.int64), sds((C, L), np.int64), sds((C, D), np.int64),
-        sds((C,), np.int64), sds((C,), f64), sds((C, D), np.int64),
-        sds((Dp, T), np.int64), sds((C,), f64),
+        sds((C, L), i64), sds((C, L), i64), sds((C, D), i64),
+        sds((C,), i64), sds((C,), f64), sds((C, D), i64),
+        sds((Dp, T), i64), sds((C,), f64),
         sds((Dp, T), f64), sds((Dp, T), f64), sds((Dp,), f64),
         sds((Dp,), f64), sds((Dp,), f64), sds((Dp,), f64), sds((Dp,), f64),
         sds((Dp,), f64), sds((Dp,), f64), sds((Dp,), f64),
     )
+    return jax.jit(batched), shapes
+
+
+def _compiled_design_kernel(jax, wl: Workload, Dp: int, C: int, L: int):
+    """AOT-compiled :func:`design_kernel_program` for the default device.
+
+    The cache key is ``(workload, "design", Dp, Cp, Lp)``; HW parameters are
+    runtime arguments exactly as in :func:`_compiled_kernel`, so one compile
+    serves every tile of a sweep that reuses the same bucketed shape.
+    """
+    key = (wl.name, "design", Dp, C, L)
+    fn = _COMPILED.get(key)
+    if fn is not None:
+        return fn
+    jitted, shapes = design_kernel_program(jax, wl, Dp, C, L)
     t0 = time.perf_counter()
     with span("mapper_batch.jax_compile", cat="mapper", workload=wl.name,
               designs=Dp, candidates=C, loops=L):
-        from jax.experimental import enable_x64
-        with enable_x64():
-            fn = jax.jit(batched).lower(*shapes).compile()
+        with jax.enable_x64(True):
+            fn = jitted.lower(*shapes).compile()
     METRICS.counter("mapper_batch.jax_compiles").inc()
     METRICS.histogram("mapper_batch.jax_compile_s").observe(
         time.perf_counter() - t0)
@@ -473,9 +512,8 @@ def perf_kernel_jax_design(
         *hw_rows,
     )
     t0 = time.perf_counter()
-    from jax.experimental import enable_x64
     with span("mapper_batch.jax_execute", cat="mapper", workload=wl.name,
-              designs=Dn, candidates=C), enable_x64():
+              designs=Dn, candidates=C), jax.enable_x64(True):
         out = fn(*args)
         out = {k: np.asarray(v) for k, v in out.items()}
     METRICS.counter("mapper_batch.jax_dispatches").inc()

@@ -19,7 +19,9 @@ makes attribution, and therefore fair retry budgets, impossible):
   up to ``max_retries`` times (``dse.retries``); a point that keeps
   failing is *quarantined*: recorded as a failure-stub
   :class:`~repro.dse.evaluate.DesignEval` (``error`` set, excluded from
-  the Pareto frontier), never a sweep abort (``dse.quarantined_points``);
+  the Pareto frontier), never a sweep abort (``dse.quarantined_points``).
+  A JAX compile or runtime error in the in-process path is the exception:
+  it is a fault of the program, not of the point, and aborts the sweep;
 * **graceful degradation** — after ``max_respawns`` worker deaths the pool
   is torn down and the remaining points run in-process sequentially;
 * **checkpointing** — completed evals and drained mapping-cache entries
@@ -207,9 +209,10 @@ _WORKER: dict = {}
 
 def _init_worker(zoo, objective, warm_entries, baseline=None,
                  trace: bool = False, faults: FaultPlan | None = None,
-                 serving=None):
+                 serving=None, engine: str = "numpy"):
     """Build this worker's Evaluator around a private in-memory mapping
-    cache, warm-started with the parent's entries.
+    cache, warm-started with the parent's entries, scoring with the
+    parent evaluator's engine.
 
     Observability state is reset first: a forked worker inherits the
     parent's trace buffer and metric totals, which would double-count on
@@ -221,7 +224,7 @@ def _init_worker(zoo, objective, warm_entries, baseline=None,
     cache.merge(warm_entries)  # merge bypasses the put() journal, so the
     _WORKER["ev"] = Evaluator(  # warm entries never echo back to the parent
         zoo=zoo, cache=cache, objective=objective, baseline=baseline,
-        serving=serving)
+        serving=serving, engine=engine)
     _WORKER["faults"] = faults
 
 
@@ -293,12 +296,23 @@ class _Worker:
 # supervisor
 # ---------------------------------------------------------------------------
 
+def _is_jax_error(err: BaseException) -> bool:
+    """A JAX compile or runtime error (e.g. the TPU compiler refusing a
+    kernel).  These are deterministic faults of the program, not poison
+    points, so they propagate instead of being retried and quarantined.
+    Checked through ``sys.modules`` so NumPy-only sweeps never import jax."""
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(err, jax.errors.JaxRuntimeError)
+
+
 class Supervisor:
     """Crash-safe :class:`DesignPoint` evaluation with in-order results.
 
     ``workers=1`` evaluates in-process (still with retry + quarantine —
-    injected crashes/hangs downgrade to exceptions there); ``workers>1``
-    runs the supervised pool.  ``completed`` (name → eval) short-circuits
+    injected crashes/hangs downgrade to exceptions there; JAX compile and
+    runtime errors propagate); ``workers>1`` runs the supervised pool, whose
+    workers score with the evaluator's engine, and is refused for
+    ``engine="jax"``.  ``completed`` (name → eval) short-circuits
     already-ledgered points on ``--resume``.  Reusable across ``map()``
     calls (the evolutionary strategy evaluates generation by generation);
     close with the context-manager protocol."""
@@ -310,6 +324,11 @@ class Supervisor:
                  completed: dict[str, DesignEval] | None = None):
         self.evaluator = evaluator
         self.workers = max(1, int(workers))
+        if self.workers > 1 and getattr(evaluator, "engine", None) == "jax":
+            # spawned workers cannot reach a chip the parent holds: refuse
+            # rather than let them score somewhere else
+            raise ValueError("engine='jax' evaluates in one process; use "
+                             "workers=1")
         self.cfg = cfg or SupervisorConfig()
         self.faults = fault_plan if (fault_plan and fault_plan.active) \
             else None
@@ -441,6 +460,8 @@ class Supervisor:
                         self.faults.fire(task.seq, in_process=True)
                     e = self.evaluator.evaluate(task.point)
                 except Exception as err:  # KeyboardInterrupt passes through
+                    if _is_jax_error(err):
+                        raise  # deterministic: a retry would fail the same
                     if not self._fail(task, f"{type(err).__name__}: {err}"):
                         self._quarantine(task, results, n, log)
                         break
@@ -457,7 +478,8 @@ class Supervisor:
         ev = self.evaluator
         return (ev.zoo, ev.objective, ev.cache.snapshot(),
                 getattr(ev, "baseline", None), tracing_enabled(),
-                self.faults, getattr(ev, "serving", None))
+                self.faults, getattr(ev, "serving", None),
+                getattr(ev, "engine", "numpy"))
 
     def _spawn_worker(self) -> _Worker:
         parent_conn, child_conn = self._ctx.Pipe(duplex=True)

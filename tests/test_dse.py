@@ -393,3 +393,34 @@ class TestBatchSweep:
         monkeypatch.setattr(pmj, "_jax", False)
         with pytest.raises(RuntimeError, match="jax"):
             batch_sweep(SPACES["tiny"], object())
+
+
+@needs_jax
+class TestCompileCache:
+    """``use_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` stands when set;
+    otherwise the AOT compiles land in the one fixed directory given."""
+
+    @pytest.mark.parametrize("env_set", [True, False])
+    def test_compiles_land_in_one_directory(self, env_set, tmp_path):
+        import os
+        import subprocess
+        import sys
+
+        default_dir, env_dir = tmp_path / "default", tmp_path / "env"
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(sys.path))
+        if env_set:
+            env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+        script = ("import sys, jax, jax.numpy as jnp\n"
+                  "from repro.core.perf_model_jax import use_compile_cache\n"
+                  "print(use_compile_cache(sys.argv[1]))\n"
+                  "jax.jit(lambda x: x * 2 + 1).lower(jnp.ones(3)).compile()\n")
+        out = subprocess.run([sys.executable, "-c", script, str(default_dir)],
+                             env=env, capture_output=True, text=True,
+                             timeout=120, check=True).stdout
+        used, unused = ((env_dir, default_dir) if env_set
+                        else (default_dir, env_dir))
+        assert out.strip() == str(used)
+        assert any(used.iterdir()), "the compile was not cached"
+        assert not unused.exists()

@@ -132,6 +132,56 @@ class TestKernelParity:
 
 
 @needs_jax
+class TestLargeTripParity:
+    """Full-width layer shapes, where loop trips multiply up to the
+    ~2.8e11-cycle range of the model zoo (``BENCH_models.json``): the int64
+    footprint contraction (a broadcast multiply and sum, not a ``dot``) and
+    every product after it must still match NumPy bit for bit, through the
+    single-design and the design-axis kernel alike."""
+
+    _DIMS = {"i": (4096, 8192, 32768), "j": (4096, 14336, 28672),
+             "k": (3584, 4096, 14336)}
+
+    @pytest.mark.parametrize("seed", range(3))
+    def test_randomized_large_batches(self, seed):
+        from repro.core.perf_model import perf_kernel
+        from repro.core.perf_model_jax import perf_kernel_jax_design
+
+        rng = random.Random(300 + seed)
+        wl, sps = _WLS["gemm"], _SP_MENU["gemm"]
+        dims_list = [{d: rng.choice(v) for d, v in self._DIMS.items()}
+                     for _ in range(3)]
+        ppu_list = [rng.choice([0.0, 4096.0]) for _ in dims_list]
+        hw_list = [HWConfig(n_fus=64,
+                            buffer_bytes=rng.choice(_HW_MENU["buffer_bytes"]),
+                            dram_gbps=rng.choice(_HW_MENU["dram_gbps"]))
+                   for _ in range(3)]
+        batch = build_batch(wl, dims_list, sps, hw_list[0])
+        ra = evaluate_batch(batch, hw_list[0], dims_list, ppu_list,
+                            engine="numpy")
+        assert ra["cycles"].max() > 1e11  # the large-trip regime
+        rb = evaluate_batch(batch, hw_list[0], dims_list, ppu_list,
+                            engine="jax")
+        _assert_kernel_parity(ra, rb, dims_list)
+
+        true = np.array([[dims[d] for d in wl.iter_dims]
+                         for dims in dims_list])[batch.layer_id]
+        ppu = np.asarray(ppu_list)[batch.layer_id]
+        dn = np.full((batch.n_candidates, len(wl.tensors)), 64)
+        rd = perf_kernel_jax_design(
+            wl, hw_list, batch.loop_dim, batch.loop_size, batch.S,
+            n_fus=batch.n_fus, fill=batch.fill, true_sizes=true,
+            data_nodes=dn[:len(hw_list)], ppu_elements=ppu)
+        for di, hw in enumerate(hw_list):
+            rn = perf_kernel(wl, hw, batch.loop_dim, batch.loop_size,
+                             batch.S, n_fus=batch.n_fus, fill=batch.fill,
+                             true_sizes=true, data_nodes=dn,
+                             ppu_elements=ppu)
+            _assert_kernel_parity(rn, {k: v[di] for k, v in rd.items()},
+                                  (di, dims_list))
+
+
+@needs_jax
 class TestThreeEngineMappingParity:
     """scalar vs numpy vs jax through the full mapping search: the winner
     and its reported LayerPerf must be byte-identical (exact — no

@@ -152,6 +152,50 @@ class TestSupervisorPool:
         assert _sig(evals) == _sig(clean_evals)
 
 
+class TestSupervisorEngine:
+    """The engine asked for is the engine that scores: no worker pool that
+    silently swaps it, no quarantine that hides a JAX compile error."""
+
+    def test_jax_engine_refuses_worker_pool(self, zoo):
+        ev = Evaluator(zoo=zoo, cache=MappingCache(), engine="jax")
+        with pytest.raises(ValueError, match="one process"):
+            Supervisor(ev, workers=2)
+
+    def test_cli_jax_engine_with_workers_is_an_argparse_error(self, capsys):
+        from benchmarks import dse
+        with pytest.raises(SystemExit) as exc:
+            dse.main(["--engine", "jax", "--workers", "2", "--dry-run"])
+        assert exc.value.code == 2
+        assert "--engine jax scores in one process" in capsys.readouterr().err
+
+    def test_pool_workers_score_with_the_evaluator_engine(self, zoo):
+        from repro.dse.supervisor import _WORKER, _init_worker
+        ev = Evaluator(zoo=zoo, cache=MappingCache(), engine="scalar")
+        try:
+            _init_worker(*Supervisor(ev, workers=2)._init_args())
+            assert _WORKER["ev"].engine == "scalar"
+        finally:
+            _WORKER.clear()
+
+    def test_jax_compile_error_propagates_unquarantined(self, zoo):
+        jax = pytest.importorskip("jax")
+        refusal = ("UNIMPLEMENTED: While rewriting computation to not "
+                   "contain X64 element types ...")
+
+        class RefusedEvaluator(Evaluator):
+            def evaluate(self, point):
+                raise jax.errors.JaxRuntimeError(refusal)
+
+        ev = RefusedEvaluator(zoo=zoo, cache=MappingCache())
+        with Supervisor(ev, cfg=SupervisorConfig(
+                max_retries=2, backoff_base_s=0.0)) as sup:
+            with pytest.raises(jax.errors.JaxRuntimeError,
+                               match="UNIMPLEMENTED"):
+                sup.map(POINTS)
+        assert sup.stats["retries"] == 0
+        assert sup.stats["quarantined"] == 0
+
+
 class TestRunLedger:
     def _eval(self, i):
         return DesignEval(point=POINTS[i], cycles=10.0 + i, energy_pj=1.0,
