@@ -32,7 +32,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from repro.obs import METRICS
+from repro.obs import METRICS, span
 
 from .mapper import (Candidate, Mapping, SpatialChoice, enumerate_candidates,
                      materialize)
@@ -75,7 +75,13 @@ def build_batch(
     hw: HWConfig,
     tile_search: bool = True,
 ) -> CandidateBatch:
-    """Enumerate + lower the candidates of every layer into one batch."""
+    """Enumerate + lower the candidates of every layer into one batch (span
+    ``mapper_batch.enumerate``)."""
+    with span("mapper_batch.enumerate", cat="mapper", workload=wl.name):
+        return _build_batch(wl, dims_list, spatials, hw, tile_search)
+
+
+def _build_batch(wl, dims_list, spatials, hw, tile_search) -> CandidateBatch:
     D = len(wl.iter_dims)
     dim_idx = {d: i for i, d in enumerate(wl.iter_dims)}
     per_layer = [enumerate_candidates(wl, dims, spatials, hw,
@@ -205,27 +211,38 @@ def best_mappings(
     METRICS.counter("mapper.batch_solves").inc()
     METRICS.counter("mapper.layers_solved").inc(len(queries))
     METRICS.counter("mapper.candidates_scored").inc(batch.n_candidates)
+    with span("mapper_batch.select", cat="mapper"):
+        winners = _winners(batch, r["cycles"], r["energy_pj"], len(queries),
+                           objective)
+    with span("mapper_batch.rescore", cat="mapper"):
+        rows = winners
+        if engine == "jax":
+            # report NumPy-exact numbers for the winners (a batch of
+            # n_layers rows — negligible next to the candidate fan-out):
+            # float-ulp drift in the XLA energies can never leak into
+            # caches or frontiers
+            r = _rescore_rows(batch, r, winners, hw, dims_list, ppu_list,
+                              data_nodes_per_tensor)
+            rows = list(range(len(queries)))  # rescored row li = winner li
+        out: list[Mapping] = []
+        for li, w in enumerate(winners):
+            cand = batch.candidates[w]
+            out.append(Mapping(materialize(wl, cand, spatials),
+                               LayerPerf.from_kernel(r, rows[li]),
+                               spatials[cand.spatial_idx]))
+    return out
+
+
+def _winners(batch: CandidateBatch, cycles: np.ndarray, energy: np.ndarray,
+             n_queries: int, objective: str) -> list[int]:
+    """Row of the objective-best candidate of every query's slice."""
     winners: list[int] = []
-    for li in range(len(queries)):
+    for li in range(n_queries):
         lo, hi = int(batch.offsets[li]), int(batch.offsets[li + 1])
         assert hi > lo, "no feasible mapping"
-        winners.append(lo + _argbest(r["cycles"][lo:hi],
-                                     r["energy_pj"][lo:hi], objective))
-    rows = winners
-    if engine == "jax":
-        # report NumPy-exact numbers for the winners (a batch of n_layers
-        # rows — negligible next to the candidate fan-out): float-ulp drift
-        # in the XLA energies can never leak into caches or frontiers
-        r = _rescore_rows(batch, r, winners, hw, dims_list, ppu_list,
-                          data_nodes_per_tensor)
-        rows = list(range(len(queries)))  # rescored row li = winner of li
-    out: list[Mapping] = []
-    for li, w in enumerate(winners):
-        cand = batch.candidates[w]
-        out.append(Mapping(materialize(wl, cand, spatials),
-                           LayerPerf.from_kernel(r, rows[li]),
-                           spatials[cand.spatial_idx]))
-    return out
+        winners.append(lo + _argbest(cycles[lo:hi], energy[lo:hi],
+                                     objective))
+    return winners
 
 
 def best_mappings_design(
@@ -295,22 +312,22 @@ def best_mappings_design(
     METRICS.counter("mapper.candidates_scored").inc(
         len(hw_list) * batch.n_candidates)
 
+    with span("mapper_batch.select", cat="mapper"):
+        winners = [_winners(batch, r["cycles"][di], r["energy_pj"][di],
+                            len(queries), objective)
+                   for di in range(len(hw_list))]
     out: list[list[Mapping]] = []
-    for di, hw in enumerate(hw_list):
-        winners: list[int] = []
-        for li in range(len(queries)):
-            lo, hi = int(batch.offsets[li]), int(batch.offsets[li + 1])
-            assert hi > lo, "no feasible mapping"
-            winners.append(lo + _argbest(r["cycles"][di, lo:hi],
-                                         r["energy_pj"][di, lo:hi],
-                                         objective))
-        dnt = (data_nodes_per_tensor_list[di]
-               if data_nodes_per_tensor_list else None)
-        rd = _rescore_rows(batch, r, winners, hw, dims_list, ppu_list, dnt)
-        out.append([Mapping(materialize(wl, batch.candidates[w], spatials),
-                            LayerPerf.from_kernel(rd, li),
-                            spatials[batch.candidates[w].spatial_idx])
-                    for li, w in enumerate(winners)])
+    with span("mapper_batch.rescore", cat="mapper"):
+        for di, hw in enumerate(hw_list):
+            dnt = (data_nodes_per_tensor_list[di]
+                   if data_nodes_per_tensor_list else None)
+            rd = _rescore_rows(batch, r, winners[di], hw, dims_list,
+                               ppu_list, dnt)
+            out.append([Mapping(materialize(wl, batch.candidates[w],
+                                            spatials),
+                                LayerPerf.from_kernel(rd, li),
+                                spatials[batch.candidates[w].spatial_idx])
+                        for li, w in enumerate(winners[di])])
     return out
 
 

@@ -41,7 +41,7 @@ import time
 
 import numpy as np
 
-from repro.obs import METRICS, span
+from repro.obs import METRICS, set_annotation_factory, span
 
 from .cost import DRAM_PJ_PER_BYTE, sram_read_pj_per_byte
 from .perf_model import HWConfig
@@ -77,6 +77,8 @@ def _import_jax():
         try:
             import jax  # deferred: keep NumPy-only processes jax-free
             _jax = jax
+            # recorded spans also land in any profiler session's own trace
+            set_annotation_factory(jax.profiler.TraceAnnotation)
         except Exception:  # pragma: no cover - environment without jax
             _jax = False
     return _jax or None
@@ -271,6 +273,58 @@ def _compiled_kernel(jax, wl: Workload, C: int, L: int):
     return fn
 
 
+def _pad_loops(loop_dim: np.ndarray, loop_size: np.ndarray, Cp: int,
+               Lp: int) -> tuple[np.ndarray, np.ndarray]:
+    """The ``(Cp, Lp)`` loop rows: padding slots are inert (dim -1, size 1)
+    and padded candidate rows replay row 0 (scored, sliced away, never
+    win)."""
+    C, L = loop_size.shape
+    ld = np.full((Cp, Lp), -1, dtype=np.int64)
+    ld[:C, :L] = loop_dim
+    ls = np.ones((Cp, Lp), dtype=np.int64)
+    ls[:C, :L] = loop_size
+    if Cp > C:
+        ld[C:] = ld[0]
+        ls[C:] = ls[0]
+    return ld, ls
+
+
+def _execute(jax, fn, args: tuple, real_rows: int, padded_rows: int,
+             **span_args) -> dict[str, np.ndarray]:
+    """One warm dispatch of a compiled kernel; returns its padded outputs
+    as host arrays.
+
+    ``mapper_batch.jax_execute`` spans the whole of it, in three phases:
+    ``transfer_in`` is the compiled call until it returns, that is the
+    call's own copy of the host arguments to the device and the launch;
+    ``device_wait`` waits for the outputs, so it holds the device work and
+    any copy-in still in flight; ``copy_out`` is one device-to-host copy
+    per output.  The wait adds nothing to the dispatch, since the first
+    copy out would wait as long, and it runs whether or not tracing is on,
+    so a traced run executes the same program.  ``real_rows`` of the
+    ``padded_rows`` scored (design × candidate) rows are real.
+    """
+    t0 = time.perf_counter()
+    with span("mapper_batch.jax_execute", cat="mapper", **span_args), \
+            jax.enable_x64(True):  # inside: int64 rows are not narrowed
+        with span("mapper_batch.transfer_in", cat="mapper"):
+            out = fn(*args)
+        with span("mapper_batch.device_wait", cat="mapper"):
+            jax.block_until_ready(out)
+        with span("mapper_batch.copy_out", cat="mapper"):
+            out = {k: np.asarray(v) for k, v in out.items()}
+    METRICS.counter("mapper_batch.jax_dispatches").inc()
+    METRICS.counter("mapper_batch.jax_candidates").inc(real_rows)
+    METRICS.counter("mapper_batch.jax_rows_padded").inc(padded_rows)
+    METRICS.counter("mapper_batch.h2d_bytes").inc(
+        sum(a.nbytes for a in args))
+    METRICS.counter("mapper_batch.d2h_bytes").inc(
+        sum(v.nbytes for v in out.values()))
+    METRICS.histogram("mapper_batch.jax_execute_s").observe(
+        time.perf_counter() - t0)
+    return out
+
+
 def _pad_rows(a: np.ndarray, C: int) -> np.ndarray:
     """Pad the candidate axis by repeating row 0 — padded rows are scored
     and discarded, never selected."""
@@ -310,44 +364,30 @@ def perf_kernel_jax(
     assert (data_nodes == data_nodes[0]).all(), \
         "engine='jax' expects one shared data-node row per batch"
     Cp, Lp = _bucket_c(C), _bucket_l(L)
-
-    tensors = list(wl.tensors)
-    budget = np.full(len(tensors), hw.buffer_bytes / len(tensors),
-                     dtype=np.float64)
-    db = np.array([hw.acc_bytes if t.role == "output" else hw.data_bytes
-                   for t in tensors], dtype=np.float64)
-
-    ld = np.full((Cp, Lp), -1, dtype=np.int64)
-    ld[:C, :L] = loop_dim
-    ls = np.ones((Cp, Lp), dtype=np.int64)
-    ls[:C, :L] = loop_size
-    if Cp > C:  # padded rows replay row 0 (scored, sliced away, never win)
-        ld[C:] = ld[0]
-        ls[C:] = ls[0]
-
     fn = _compiled_kernel(jax, wl, Cp, Lp)
-    args = (
-        ld, ls, _pad_rows(S, Cp), _pad_rows(n_fus, Cp),
-        _pad_rows(fill.astype(np.float64), Cp), _pad_rows(true_sizes, Cp),
-        np.asarray(data_nodes[0], dtype=np.int64),
-        _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
-        budget, db,
-        np.float64(hw.bytes_per_cycle), np.float64(max(1, hw.n_ppus)),
-        np.float64(hw.e_mac_pj), np.float64(hw.e_reg_pj_per_byte),
-        np.float64(hw.e_ppu_pj),
-        np.float64(hw.static_mw / hw.freq_ghz * 1e-3),  # mW·ns = pJ
-        np.float64(sram_read_pj_per_byte(hw.buffer_bytes)),
-        np.float64(hw.data_bytes),
-    )
-    t0 = time.perf_counter()
-    with span("mapper_batch.jax_execute", cat="mapper", workload=wl.name,
-              candidates=C), jax.enable_x64(True):
-        out = fn(*args)
-        out = {k: np.asarray(v) for k, v in out.items()}
-    METRICS.counter("mapper_batch.jax_dispatches").inc()
-    METRICS.counter("mapper_batch.jax_candidates").inc(C)
-    METRICS.histogram("mapper_batch.jax_execute_s").observe(
-        time.perf_counter() - t0)
+
+    with span("mapper_batch.pack", cat="mapper"):
+        tensors = list(wl.tensors)
+        budget = np.full(len(tensors), hw.buffer_bytes / len(tensors),
+                         dtype=np.float64)
+        db = np.array([hw.acc_bytes if t.role == "output" else hw.data_bytes
+                       for t in tensors], dtype=np.float64)
+        args = (
+            *_pad_loops(loop_dim, loop_size, Cp, Lp),
+            _pad_rows(S, Cp), _pad_rows(n_fus, Cp),
+            _pad_rows(fill.astype(np.float64), Cp),
+            _pad_rows(true_sizes, Cp),
+            np.asarray(data_nodes[0], dtype=np.int64),
+            _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
+            budget, db,
+            np.float64(hw.bytes_per_cycle), np.float64(max(1, hw.n_ppus)),
+            np.float64(hw.e_mac_pj), np.float64(hw.e_reg_pj_per_byte),
+            np.float64(hw.e_ppu_pj),
+            np.float64(hw.static_mw / hw.freq_ghz * 1e-3),  # mW·ns = pJ
+            np.float64(sram_read_pj_per_byte(hw.buffer_bytes)),
+            np.float64(hw.data_bytes),
+        )
+    out = _execute(jax, fn, args, C, Cp, workload=wl.name, candidates=C)
     return {k: v[:C] for k, v in out.items()}
 
 
@@ -489,38 +529,23 @@ def perf_kernel_jax_design(
     Cp = _bucket_c(max(C, min_c))
     Lp = _bucket_l(max(L, min_l))
     Dp = _bucket_c(max(Dn, min_d))
-
-    ld = np.full((Cp, Lp), -1, dtype=np.int64)
-    ld[:C, :L] = loop_dim
-    ls = np.ones((Cp, Lp), dtype=np.int64)
-    ls[:C, :L] = loop_size
-    if Cp > C:  # padded rows replay row 0 (scored, sliced away, never win)
-        ld[C:] = ld[0]
-        ls[C:] = ls[0]
-
-    hw_rows = _hw_rows(hw_list, list(wl.tensors))
-    dn = np.asarray(data_nodes, dtype=np.int64)
-    # pad the design axis by repeating design 0 (scored, sliced away)
-    hw_rows = tuple(_pad_rows(a, Dp) for a in hw_rows)
-    dn = _pad_rows(dn, Dp)
-
     fn = _compiled_design_kernel(jax, wl, Dp, Cp, Lp)
-    args = (
-        ld, ls, _pad_rows(S, Cp), _pad_rows(n_fus, Cp),
-        _pad_rows(fill.astype(np.float64), Cp), _pad_rows(true_sizes, Cp),
-        dn, _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
-        *hw_rows,
-    )
-    t0 = time.perf_counter()
-    with span("mapper_batch.jax_execute", cat="mapper", workload=wl.name,
-              designs=Dn, candidates=C), jax.enable_x64(True):
-        out = fn(*args)
-        out = {k: np.asarray(v) for k, v in out.items()}
-    METRICS.counter("mapper_batch.jax_dispatches").inc()
-    METRICS.counter("mapper_batch.jax_candidates").inc(Dn * C)
-    METRICS.counter("mapper_batch.jax_design_points").inc(Dn)
-    METRICS.histogram("mapper_batch.jax_execute_s").observe(
-        time.perf_counter() - t0)
+
+    with span("mapper_batch.pack", cat="mapper"):
+        # pad the design axis by repeating design 0 (scored, sliced away)
+        hw_rows = tuple(_pad_rows(a, Dp)
+                        for a in _hw_rows(hw_list, list(wl.tensors)))
+        args = (
+            *_pad_loops(loop_dim, loop_size, Cp, Lp),
+            _pad_rows(S, Cp), _pad_rows(n_fus, Cp),
+            _pad_rows(fill.astype(np.float64), Cp),
+            _pad_rows(true_sizes, Cp),
+            _pad_rows(np.asarray(data_nodes, dtype=np.int64), Dp),
+            _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
+            *hw_rows,
+        )
+    out = _execute(jax, fn, args, Dn * C, Dp * Cp, workload=wl.name,
+                   designs=Dn, candidates=C)
     return {k: v[:Dn, :C] for k, v in out.items()}
 
 

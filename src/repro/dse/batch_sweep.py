@@ -115,10 +115,11 @@ def _prefill_tile(evaluator: Evaluator, tile: list[DesignPoint],
     hw_list = [p.hw_config() for p in tile]
     added = 0
     for wl, sps, dn, queries in _prefill_queries(evaluator, tile[0]):
-        keys = [[mapping_key(wl, dims, sps, hw, dn, ppu, objective)
-                 for dims, ppu in queries] for hw in hw_list]
-        need_d = [di for di in range(len(tile))
-                  if any(not cache.contains(k) for k in keys[di])]
+        with span("mapper_cache.keys", cat="mapper"):
+            keys = [[mapping_key(wl, dims, sps, hw, dn, ppu, objective)
+                     for dims, ppu in queries] for hw in hw_list]
+            need_d = [di for di in range(len(tile))
+                      if any(not cache.contains(k) for k in keys[di])]
         if not need_d:
             continue
         # solve the full query set for every design that misses anything:

@@ -40,7 +40,7 @@ from repro.core.mapper import Mapping, SpatialChoice, best_mapping
 from repro.core.mapper_batch import best_mappings
 from repro.core.perf_model import HWConfig, LayerPerf
 from repro.core.workload import Workload
-from repro.obs import METRICS, get_logger
+from repro.obs import METRICS, get_logger, span
 
 __all__ = ["MappingCache", "mapping_key", "atomic_write_json",
            "entry_checksum"]
@@ -344,16 +344,18 @@ class MappingCache:
         engine field, so caches are interchangeable across engines
         (``engine="scalar"`` falls back to per-query reference solves).
         """
-        keys = [mapping_key(wl, dims, spatials, hw, data_nodes_per_tensor,
-                            ppu, objective) for dims, ppu in queries]
-        out: list[LayerPerf | None] = [None] * len(queries)
-        miss: list[int] = []
-        for i, k in enumerate(keys):
-            e = self.get(k)
-            if e is not None:
-                out[i] = LayerPerf.from_dict(e["perf"])
-            else:
-                miss.append(i)
+        with span("mapper_cache.keys", cat="mapper"):
+            keys = [mapping_key(wl, dims, spatials, hw,
+                                data_nodes_per_tensor, ppu, objective)
+                    for dims, ppu in queries]
+            out: list[LayerPerf | None] = [None] * len(queries)
+            miss: list[int] = []
+            for i, k in enumerate(keys):
+                e = self.get(k)
+                if e is not None:
+                    out[i] = LayerPerf.from_dict(e["perf"])
+                else:
+                    miss.append(i)
         if miss:
             if engine == "scalar":
                 solved = [best_mapping(

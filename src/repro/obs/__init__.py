@@ -27,14 +27,15 @@ from .metrics import (METRICS, Counter, Gauge, Histogram, Registry,
                       metrics_enabled, set_metrics_enabled)
 from .provenance import PROVENANCE_SCHEMA, git_sha, provenance_record
 from .trace import (Span, Tracer, disable_tracing, drain_events,
-                    enable_tracing, instant, merge_events, save_trace, span,
-                    span_counts, tracing_enabled)
+                    enable_tracing, instant, merge_events, save_trace,
+                    set_annotation_factory, span, span_counts,
+                    tracing_enabled)
 from .vcd import VCDWriter
 
 __all__ = [
     "span", "instant", "Span", "Tracer", "enable_tracing", "disable_tracing",
     "tracing_enabled", "drain_events", "merge_events", "save_trace",
-    "span_counts",
+    "span_counts", "set_annotation_factory",
     "METRICS", "Registry", "Counter", "Gauge", "Histogram",
     "set_metrics_enabled", "metrics_enabled",
     "PROVENANCE_SCHEMA", "provenance_record", "git_sha",

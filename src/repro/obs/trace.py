@@ -13,7 +13,13 @@ Design constraints, in order:
    each result and the parent ``merge_events()`` them, so one trace file
    covers the whole pool.  Events carry the recording ``pid``/``tid``, so
    Perfetto renders one track per worker.
-3. **Determinism where it matters.**  Wall timestamps are inherently
+3. **One clock with the device.**  An annotation factory, when one is set
+   (:func:`set_annotation_factory`; the JAX scoring engine registers
+   ``jax.profiler.TraceAnnotation`` on its first JAX import), also opens a
+   profiler annotation of the same name around every span recorded while
+   tracing is on, so any profiler session holds the program's spans on
+   its own clock, nested over the device's operations.
+4. **Determinism where it matters.**  Wall timestamps are inherently
    run-dependent; :func:`span_counts` projects a trace onto its
    deterministic skeleton (span name → occurrence count), which is what the
    workers=1 vs workers=N equivalence test asserts.
@@ -40,7 +46,8 @@ import time
 
 __all__ = ["Span", "Tracer", "span", "instant", "enable_tracing",
            "disable_tracing", "tracing_enabled", "drain_events",
-           "merge_events", "save_trace", "span_counts", "trace_preamble"]
+           "merge_events", "save_trace", "span_counts", "trace_preamble",
+           "set_annotation_factory"]
 
 
 class Tracer:
@@ -71,6 +78,7 @@ class Tracer:
 
 _TRACER = Tracer()
 _ENABLED = False
+_ANNOTATION = None   # name -> context manager opened around recorded spans
 
 
 def enable_tracing() -> None:
@@ -86,6 +94,14 @@ def disable_tracing() -> None:
 
 def tracing_enabled() -> bool:
     return _ENABLED
+
+
+def set_annotation_factory(factory) -> None:
+    """Open ``factory(name)`` (a context manager) around every span recorded
+    from now on; ``None`` stops it.  The factory is how a profiler's own
+    trace (``jax.profiler.TraceAnnotation``) gets the program's spans."""
+    global _ANNOTATION
+    _ANNOTATION = factory
 
 
 def drain_events() -> list[dict]:
@@ -105,10 +121,11 @@ class Span:
 
     Always measures (``duration_s`` is valid whether or not tracing is
     enabled); records a Chrome complete event (``ph: "X"``, microsecond
-    timestamps) only when tracing is on at entry.
+    timestamps) only when tracing is on at entry, and then also holds the
+    annotation factory's annotation open over the same interval.
     """
 
-    __slots__ = ("name", "cat", "args", "t0", "t1", "_record")
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_record", "_ann")
 
     def __init__(self, name: str, cat: str = "repro", **args):
         self.name = name
@@ -117,6 +134,7 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self._record = False
+        self._ann = None
 
     @property
     def duration_s(self) -> float:
@@ -124,11 +142,17 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._record = _ENABLED
+        if self._record and _ANNOTATION is not None:
+            self._ann = _ANNOTATION(self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if self._record:
             ev = {"name": self.name, "cat": self.cat, "ph": "X",
                   "ts": self.t0 * 1e6, "dur": (self.t1 - self.t0) * 1e6,

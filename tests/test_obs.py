@@ -1,5 +1,6 @@
 """Observability tests: span/trace API, metrics registry, worker-pool trace
-merge determinism, rtlsim hardware introspection (utilization parity vs the
+merge determinism, the scoring engine's dispatch phases and their profiler
+annotations, rtlsim hardware introspection (utilization parity vs the
 closed-form perf model, stall bookkeeping), the deterministic VCD writer
 (golden snapshot) and the bench-JSON provenance/metrics schema."""
 
@@ -15,7 +16,10 @@ from repro.core.adg import generate_adg
 from repro.core.dag import codegen
 from repro.core.dataflow import build_dataflow
 from repro.core.passes import run_backend
+from repro.core.mapper import SpatialChoice
+from repro.core.mapper_batch import best_mappings, best_mappings_design
 from repro.core.perf_model import HWConfig, layer_perf
+from repro.core.perf_model_jax import jax_available
 from repro.core.rtlsim import simulate_rtl
 from repro.dse import SPACES, DesignPoint, Evaluator, MappingCache, run_search
 from repro.dse.evaluate import DesignEval, lower_config
@@ -24,7 +28,8 @@ from repro.dse.search import SearchResult
 from repro.obs import (METRICS, PROVENANCE_SCHEMA, Gauge, Histogram,
                        Registry, VCDWriter, disable_tracing, drain_events,
                        enable_tracing, metrics_enabled, provenance_record,
-                       save_trace, set_metrics_enabled, span, span_counts,
+                       save_trace, set_annotation_factory,
+                       set_metrics_enabled, span, span_counts,
                        tracing_enabled)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden", "tiny_wave.vcd")
@@ -72,6 +77,40 @@ class TestSpan:
         with sp:
             enable_tracing()  # too late for this span
         assert drain_events() == []
+
+    def test_annotation_factory_wraps_recorded_spans(self, monkeypatch):
+        from repro.obs import trace
+
+        seen = []
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("open", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("close", self.name))
+
+        # restored after the test: the JAX engine may have set its own
+        monkeypatch.setattr(trace, "_ANNOTATION", trace._ANNOTATION)
+        set_annotation_factory(Ann)
+        with span("untraced"):
+            pass
+        enable_tracing()
+        with span("outer"):
+            with pytest.raises(ValueError):
+                with span("inner"):
+                    raise ValueError("x")
+        assert seen == [("open", "outer"), ("open", "inner"),
+                        ("close", "inner"), ("close", "outer")]
+        set_annotation_factory(None)
+        with span("bare"):
+            pass
+        assert len(seen) == 4
+        assert span_counts(drain_events()) == {"bare": 1, "inner": 1,
+                                               "outer": 1}
 
     def test_decorator(self):
         enable_tracing()
@@ -170,20 +209,33 @@ def _tiny_sweep(workers: int):
     return result, span_counts(drain_events()), METRICS.drain()
 
 
+# spans that fire once per cache-miss solve: their count follows the
+# solves, which depend on the pool size like the hit/miss counters
+MISS_SPANS = ("mapper_batch.enumerate", "mapper_batch.select",
+              "mapper_batch.rescore")
+
+
 class TestWorkerPoolMerge:
     def test_trace_and_metrics_identical_across_worker_counts(self):
         """The trace skeleton (span name → count) and the worker-count-
         invariant counters of a sweep must not depend on the pool size —
         workers drain their buffers with each result and the parent merges.
         (Cache hit/miss counters legitimately differ: each worker's private
-        cache re-solves shapes a sequential run would have cached.)"""
+        cache re-solves shapes a sequential run would have cached; so do
+        the spans of a solve, which each run must count once per solve.)"""
         enable_tracing()
         r1, spans1, metrics1 = _tiny_sweep(workers=1)
         drain_events()
         r4, spans4, metrics4 = _tiny_sweep(workers=4)
         n = len(list(SPACES["tiny"].enumerate()))
+        for spans, metrics in ((spans1, metrics1), (spans4, metrics4)):
+            solves = metrics["counters"]["mapper.batch_solves"]
+            assert solves > 0
+            for name in MISS_SPANS:
+                assert spans.pop(name) == solves, name
         assert spans1 == spans4
         assert spans1["dse.evaluate"] == n
+        assert spans1["mapper_cache.keys"] >= n  # one per (design, kind)
         assert spans1["dse.exhaustive_search"] == 1
         for key in ("dse.designs_scored", "dse.designs_fused_capable",
                     "dse.designs_unfused"):
@@ -196,6 +248,130 @@ class TestWorkerPoolMerge:
     def test_wall_s_comes_from_the_span(self):
         r, _, _ = _tiny_sweep(workers=1)
         assert r.wall_s > 0.0
+
+
+# ---------------------------------------------------------------------------
+# scoring-engine dispatch phases and host mapping-search spans
+# ---------------------------------------------------------------------------
+
+needs_jax = pytest.mark.skipif(not jax_available(),
+                               reason="jax runtime not importable")
+PHASES = ("mapper_batch.transfer_in", "mapper_batch.device_wait",
+          "mapper_batch.copy_out")
+GEMM_MENU = [SpatialChoice(("i", "j"), (1, 1), "ij"),
+             SpatialChoice(("k", "j"), (1, 1), "jk")]
+GEMM_QUERIES = [({"i": 16, "j": 16, "k": 16}, 0.0),
+                ({"i": 56, "j": 7, "k": 130}, 4096.0)]
+
+
+def _solve_both():
+    """One per-design JAX solve and one design-batched solve, each
+    enumerating its own batch: two ``build_batch`` calls, two solves."""
+    wl = W.gemm()
+    hw = [HWConfig(n_fus=64, buffer_bytes=kb * 1024) for kb in (64, 512)]
+    best_mappings(wl, GEMM_QUERIES, GEMM_MENU, hw[0], engine="jax")
+    best_mappings_design(wl, GEMM_QUERIES, GEMM_MENU, hw)
+
+
+def _inside(inner, outer) -> bool:
+    return outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+@needs_jax
+class TestScoringSpans:
+    def test_dispatch_phases_nest_in_each_execute(self, monkeypatch):
+        from repro.core import perf_model_jax
+
+        put, copied = [], []
+        real = perf_model_jax._execute
+
+        def spy(jax, fn, args, *a, **kw):
+            put.append(sum(np.asarray(v).nbytes for v in args))
+            out = real(jax, fn, args, *a, **kw)  # padded host outputs
+            copied.append(sum(v.nbytes for v in out.values()))
+            return out
+
+        _solve_both()  # compiles outside the traced calls
+        METRICS.reset()
+        monkeypatch.setattr(perf_model_jax, "_execute", spy)
+        enable_tracing()
+        _solve_both()
+        events = [e for e in drain_events() if e["ph"] == "X"]
+        execs = [e for e in events if e["name"] == "mapper_batch.jax_execute"]
+        assert len(execs) == 2
+        for ex in execs:
+            inside = [e for e in events if e["name"] in PHASES
+                      and _inside(e, ex)]
+            assert [e["name"] for e in inside] == list(PHASES)
+            for a, b in zip(inside, inside[1:]):  # in order, disjoint
+                assert a["ts"] + a["dur"] <= b["ts"]
+        assert span_counts(events) == {
+            "mapper_batch.enumerate": 2, "mapper_batch.pack": 2,
+            "mapper_batch.jax_execute": 2, **{p: 2 for p in PHASES},
+            "mapper_batch.select": 2, "mapper_batch.rescore": 2}
+        # the host search and packing lie outside the dispatch
+        for e in events:
+            if e["name"] not in PHASES + ("mapper_batch.jax_execute",):
+                assert not any(_inside(e, x) for x in execs), e["name"]
+        c = METRICS.snapshot()["counters"]
+        assert c["mapper_batch.h2d_bytes"] == sum(put) > 0
+        assert c["mapper_batch.d2h_bytes"] == sum(copied) > 0
+        assert c["mapper_batch.jax_rows_padded"] >= \
+            c["mapper_batch.jax_candidates"] > 0
+        assert "mapper_batch.jax_design_points" not in c
+
+    def test_annotations_share_the_profiler_clock(self, tmp_path):
+        """Recorded spans are annotations of a profiler session too: same
+        names and nesting on the host plane, and the program's own times,
+        mapped through one marker's offset as the benchmark maps them,
+        agree with the annotations within 1 ms."""
+        import glob
+        import time
+
+        import jax
+        from jax.profiler import ProfileData
+
+        _solve_both()
+        enable_tracing()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("bench.window"):
+                mark_ns = time.perf_counter_ns()
+                _solve_both()
+        finally:
+            jax.profiler.stop_trace()
+        events = [e for e in drain_events() if e["ph"] == "X"]
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        names = {e["name"] for e in events} | {"bench.window"}
+        ann = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+               for plane in ProfileData.from_file(path).planes
+               if plane.name == "/host:CPU"
+               for line in plane.lines for e in line.events
+               if e.name in names]
+        (mark,) = [a for a in ann if a[0] == "bench.window"]
+        ann = sorted(a for a in ann if a[0] != "bench.window")
+        off = mark[1] - mark_ns
+        mine = sorted((e["name"], e["ts"] * 1e3 + off,
+                       (e["ts"] + e["dur"]) * 1e3 + off) for e in events)
+        assert [a[0] for a in ann] == [m[0] for m in mine]
+        for a, m in zip(sorted(ann, key=lambda x: x[1]),
+                        sorted(mine, key=lambda x: x[1])):
+            assert a[0] == m[0]
+            assert abs(a[1] - m[1]) < 1e6 and abs(a[2] - m[2]) < 1e6, a[0]
+
+        def nesting(spans):
+            return sorted((inner[0], outer[0]) for inner in spans
+                          for outer in spans if inner is not outer
+                          and outer[1] <= inner[1] and inner[2] <= outer[2])
+
+        assert nesting(ann) == nesting(mine)
+        assert ("mapper_batch.device_wait", "mapper_batch.jax_execute") \
+            in nesting(ann)
 
 
 # ---------------------------------------------------------------------------
