@@ -133,7 +133,8 @@ def evaluate_batch(
     kernels; ``engine="jax"`` runs the jitted XLA port — integer-derived
     outputs are bit-identical, ``energy_pj`` within
     :data:`repro.core.perf_model_jax.ENERGY_RTOL` (see that module for the
-    tolerance policy)."""
+    tolerance policy), returned in a read-only mapping that copies each
+    output but ``cycles`` and ``energy_pj`` from the device on first read."""
     wl = batch.wl
     D = len(wl.iter_dims)
     n_layers = len(dims_list)

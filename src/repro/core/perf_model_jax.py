@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import os
 import time
+from collections.abc import Mapping
 
 import numpy as np
 
@@ -289,20 +290,56 @@ def _pad_loops(loop_dim: np.ndarray, loop_size: np.ndarray, Cp: int,
     return ld, ls
 
 
+# the outputs host selection reads: every objective ranks by these two
+EAGER_OUTPUTS = ("cycles", "energy_pj")
+
+
+class KernelOutputs(Mapping):
+    """A dispatch's outputs, each sliced to the real rows: the
+    :data:`EAGER_OUTPUTS` as host arrays copied by the dispatch, every
+    other output still on the device until its first read, which copies it
+    (span ``mapper_batch.copy_out_late``) and keeps the host array.  The
+    device buffers go with the mapping."""
+
+    def __init__(self, out: dict, rows: tuple):
+        self._out = out    # name -> host array (sliced) or device array
+        self._rows = rows  # the index of the real rows
+
+    def __getitem__(self, key: str) -> np.ndarray:
+        v = self._out[key]
+        if not isinstance(v, np.ndarray):
+            with span("mapper_batch.copy_out_late", cat="mapper",
+                      output=key):
+                v = np.asarray(v)
+            METRICS.counter("mapper_batch.outputs_fetched_late").inc()
+            METRICS.counter("mapper_batch.d2h_bytes").inc(v.nbytes)
+            v = self._out[key] = v[self._rows]
+        return v
+
+    def __iter__(self):
+        return iter(self._out)
+
+    def __len__(self) -> int:
+        return len(self._out)
+
+
 def _execute(jax, fn, args: tuple, real_rows: int, padded_rows: int,
-             **span_args) -> dict[str, np.ndarray]:
-    """One warm dispatch of a compiled kernel; returns its padded outputs
-    as host arrays.
+             rows: tuple, **span_args) -> KernelOutputs:
+    """One warm dispatch of a compiled kernel; returns its outputs indexed
+    by ``rows`` (the real rows of the padded outputs).
 
     ``mapper_batch.jax_execute`` spans the whole of it, in three phases:
     ``transfer_in`` is the compiled call until it returns, that is the
     call's own copy of the host arguments to the device and the launch;
     ``device_wait`` waits for the outputs, so it holds the device work and
-    any copy-in still in flight; ``copy_out`` is one device-to-host copy
-    per output.  The wait adds nothing to the dispatch, since the first
-    copy out would wait as long, and it runs whether or not tracing is on,
-    so a traced run executes the same program.  ``real_rows`` of the
-    ``padded_rows`` scored (design × candidate) rows are real.
+    any copy-in still in flight; ``copy_out`` copies the
+    :data:`EAGER_OUTPUTS` to the host, both copies issued before either is
+    waited for, and leaves the other outputs on the device
+    (:class:`KernelOutputs`).  The wait adds nothing to the dispatch, since
+    the first copy out would wait as long, and it runs whether or not
+    tracing is on, so a traced run executes the same program.
+    ``real_rows`` of the ``padded_rows`` scored (design × candidate) rows
+    are real.
     """
     t0 = time.perf_counter()
     with span("mapper_batch.jax_execute", cat="mapper", **span_args), \
@@ -312,17 +349,20 @@ def _execute(jax, fn, args: tuple, real_rows: int, padded_rows: int,
         with span("mapper_batch.device_wait", cat="mapper"):
             jax.block_until_ready(out)
         with span("mapper_batch.copy_out", cat="mapper"):
-            out = {k: np.asarray(v) for k, v in out.items()}
+            host = jax.device_get({k: out[k] for k in EAGER_OUTPUTS})
     METRICS.counter("mapper_batch.jax_dispatches").inc()
     METRICS.counter("mapper_batch.jax_candidates").inc(real_rows)
     METRICS.counter("mapper_batch.jax_rows_padded").inc(padded_rows)
     METRICS.counter("mapper_batch.h2d_bytes").inc(
         sum(a.nbytes for a in args))
     METRICS.counter("mapper_batch.d2h_bytes").inc(
-        sum(v.nbytes for v in out.values()))
+        sum(v.nbytes for v in host.values()))
+    METRICS.counter("mapper_batch.outputs_deferred").inc(
+        len(out) - len(host))
     METRICS.histogram("mapper_batch.jax_execute_s").observe(
         time.perf_counter() - t0)
-    return out
+    return KernelOutputs({k: host[k][rows] if k in host else v
+                          for k, v in out.items()}, rows)
 
 
 def _pad_rows(a: np.ndarray, C: int) -> np.ndarray:
@@ -346,14 +386,16 @@ def perf_kernel_jax(
     true_sizes: np.ndarray,
     data_nodes: np.ndarray,
     ppu_elements: np.ndarray,
-) -> dict[str, np.ndarray]:
+) -> Mapping[str, np.ndarray]:
     """Drop-in JAX replacement for :func:`repro.core.perf_model.perf_kernel`.
 
     Same candidate row encoding, same result keys; the whole batch scores in
     one XLA dispatch.  ``data_nodes`` rows must be identical across the
     batch (the mapper-batch invariant: one data-node vector per query set) —
     asserted, because the vmapped kernel broadcasts a single ``(T,)`` row.
-    Results come back as host NumPy arrays sliced to the true batch size.
+    Results come back as a read-only mapping of host NumPy arrays sliced to
+    the true batch size; outputs other than :data:`EAGER_OUTPUTS` are
+    copied from the device on first read (:class:`KernelOutputs`).
     """
     jax = _require_jax()
     C, L = loop_size.shape
@@ -387,8 +429,8 @@ def perf_kernel_jax(
             np.float64(sram_read_pj_per_byte(hw.buffer_bytes)),
             np.float64(hw.data_bytes),
         )
-    out = _execute(jax, fn, args, C, Cp, workload=wl.name, candidates=C)
-    return {k: v[:C] for k, v in out.items()}
+    return _execute(jax, fn, args, C, Cp, (slice(C),), workload=wl.name,
+                    candidates=C)
 
 
 # ---------------------------------------------------------------------------
@@ -501,13 +543,15 @@ def perf_kernel_jax_design(
     min_c: int = 1,
     min_l: int = 4,
     min_d: int = 1,
-) -> dict[str, np.ndarray]:
+) -> Mapping[str, np.ndarray]:
     """Score one candidate batch against **D designs** in one XLA dispatch.
 
     Candidate arrays are the shared ``(C, …)`` row encoding of
     :func:`perf_kernel_jax` (all designs must enumerate the identical
     candidate set — callers group designs by ``n_fus``); ``data_nodes`` is
-    one ``(D, T)`` row per design.  Returns ``(D, C)``-shaped host arrays.
+    one ``(D, T)`` row per design.  Returns ``(D, C)``-shaped host arrays,
+    in a mapping that copies each output but :data:`EAGER_OUTPUTS` on first
+    read, as :func:`perf_kernel_jax` does.
 
     ``min_c`` / ``min_l`` / ``min_d`` are bucket floors: a sweep
     orchestrator passes its running per-workload maxima so every tile lands
@@ -544,9 +588,8 @@ def perf_kernel_jax_design(
             _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
             *hw_rows,
         )
-    out = _execute(jax, fn, args, Dn * C, Dp * Cp, workload=wl.name,
-                   designs=Dn, candidates=C)
-    return {k: v[:Dn, :C] for k, v in out.items()}
+    return _execute(jax, fn, args, Dn * C, Dp * Cp, (slice(Dn), slice(C)),
+                    workload=wl.name, designs=Dn, candidates=C)
 
 
 def _transpose_dicts(dicts: list[dict]) -> dict[str, list]:

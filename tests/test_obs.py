@@ -286,10 +286,16 @@ class TestScoringSpans:
         put, copied = [], []
         real = perf_model_jax._execute
 
-        def spy(jax, fn, args, *a, **kw):
+        def spy(jax, fn, args, real_rows, padded_rows, *a, **kw):
             put.append(sum(np.asarray(v).nbytes for v in args))
-            out = real(jax, fn, args, *a, **kw)  # padded host outputs
-            copied.append(sum(v.nbytes for v in out.values()))
+            out = real(jax, fn, args, real_rows, padded_rows, *a, **kw)
+            eager = [out[k] for k in perf_model_jax.EAGER_OUTPUTS]
+            copied.append(padded_rows * sum(v.dtype.itemsize for v in eager))
+            if len(copied) == 2:  # read every output of the second dispatch
+                late = [v for k, v in out.items()
+                        if k not in perf_model_jax.EAGER_OUTPUTS]
+                copied.append(padded_rows *
+                              sum(v.dtype.itemsize for v in late))
             return out
 
         _solve_both()  # compiles outside the traced calls
@@ -309,14 +315,18 @@ class TestScoringSpans:
         assert span_counts(events) == {
             "mapper_batch.enumerate": 2, "mapper_batch.pack": 2,
             "mapper_batch.jax_execute": 2, **{p: 2 for p in PHASES},
+            "mapper_batch.copy_out_late": 6,
             "mapper_batch.select": 2, "mapper_batch.rescore": 2}
-        # the host search and packing lie outside the dispatch
+        # the host search, packing and late copies lie outside the dispatch
         for e in events:
             if e["name"] not in PHASES + ("mapper_batch.jax_execute",):
                 assert not any(_inside(e, x) for x in execs), e["name"]
         c = METRICS.snapshot()["counters"]
         assert c["mapper_batch.h2d_bytes"] == sum(put) > 0
+        # bytes actually copied: two scores a dispatch, six outputs late
         assert c["mapper_batch.d2h_bytes"] == sum(copied) > 0
+        assert c["mapper_batch.outputs_deferred"] == 12
+        assert c["mapper_batch.outputs_fetched_late"] == 6
         assert c["mapper_batch.jax_rows_padded"] >= \
             c["mapper_batch.jax_candidates"] > 0
         assert "mapper_batch.jax_design_points" not in c
