@@ -1,7 +1,7 @@
 """Architecture registry: one module per assigned architecture, each with
 ``full()`` (the exact published config) and ``smoke()`` (a reduced config of
 the same family for CPU tests).  ``get_config(name, reduced=...)`` resolves
-by id; ``ARCH_IDS`` lists all ten assigned architectures."""
+by id; ``ARCH_IDS`` lists every architecture."""
 
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ ARCH_IDS = [
     "gemma2_9b",
     "llama4_scout_17b_a16e",
     "deepseek_moe_16b",
+    "deepseek_v3_671b",
     "phi_3_vision_4_2b",
     "whisper_base",
 ]
@@ -27,6 +28,8 @@ ALIASES.update({
     "llama4-scout-17b-a16e": "llama4_scout_17b_a16e",
     "phi-3-vision-4.2b": "phi_3_vision_4_2b",
     "deepseek-moe-16b": "deepseek_moe_16b",
+    "deepseek-v3-671b": "deepseek_v3_671b",
+    "deepseek-v3": "deepseek_v3_671b",
     "mistral-nemo-12b": "mistral_nemo_12b",
     "rwkv6-7b": "rwkv6_7b",
     "gemma-7b": "gemma_7b",

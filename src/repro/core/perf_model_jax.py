@@ -324,7 +324,7 @@ class KernelOutputs(Mapping):
 
 
 def _execute(jax, fn, args: tuple, real_rows: int, padded_rows: int,
-             rows: tuple, **span_args) -> KernelOutputs:
+             rows: tuple, workload: str, **span_args) -> KernelOutputs:
     """One warm dispatch of a compiled kernel; returns its outputs indexed
     by ``rows`` (the real rows of the padded outputs).
 
@@ -339,10 +339,11 @@ def _execute(jax, fn, args: tuple, real_rows: int, padded_rows: int,
     the first copy out would wait as long, and it runs whether or not
     tracing is on, so a traced run executes the same program.
     ``real_rows`` of the ``padded_rows`` scored (design × candidate) rows
-    are real.
+    are real; they are counted in total and per ``workload`` kind.
     """
     t0 = time.perf_counter()
-    with span("mapper_batch.jax_execute", cat="mapper", **span_args), \
+    with span("mapper_batch.jax_execute", cat="mapper", workload=workload,
+              **span_args), \
             jax.enable_x64(True):  # inside: int64 rows are not narrowed
         with span("mapper_batch.transfer_in", cat="mapper"):
             out = fn(*args)
@@ -352,6 +353,7 @@ def _execute(jax, fn, args: tuple, real_rows: int, padded_rows: int,
             host = jax.device_get({k: out[k] for k in EAGER_OUTPUTS})
     METRICS.counter("mapper_batch.jax_dispatches").inc()
     METRICS.counter("mapper_batch.jax_candidates").inc(real_rows)
+    METRICS.counter(f"mapper_batch.jax_candidates.{workload}").inc(real_rows)
     METRICS.counter("mapper_batch.jax_rows_padded").inc(padded_rows)
     METRICS.counter("mapper_batch.h2d_bytes").inc(
         sum(a.nbytes for a in args))

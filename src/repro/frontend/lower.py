@@ -32,6 +32,7 @@ from typing import Iterable
 
 from repro.configs import ARCH_IDS, get_config
 from repro.models.common import ModelConfig
+from repro.obs import span
 
 from .model_graph import PHASES, ModelGraph, build_model_graph
 
@@ -99,10 +100,11 @@ def lower_model(cfg: ModelConfig | str, *, seq: int = 512, batch: int = 1,
     :func:`unfuse_attention_rows`)."""
     if isinstance(cfg, str):
         cfg = get_config(cfg, reduced=reduced)
-    graph = build_model_graph(cfg, seq=seq, batch=batch, phase=phase,
-                              lm_head=lm_head,
-                              fused_attention=fused_attention)
-    return graph.lowered()
+    with span("frontend.lower", cat="frontend", model=cfg.name, phase=phase,
+              seq=seq):
+        return build_model_graph(cfg, seq=seq, batch=batch, phase=phase,
+                                 lm_head=lm_head,
+                                 fused_attention=fused_attention).lowered()
 
 
 def zoo_key(name: str, phase: str, phases: Iterable[str]) -> str:

@@ -20,6 +20,7 @@ execution *phase*:
 
 The graph covers every family in ``repro.configs``: dense/GQA/MQA attention
 (``n_kv_heads`` shrinks the KV projection), sliding-window attention,
+multi-head latent attention (naive in prefill, absorbed in decode),
 MoE routed + shared experts, Mamba SSM blocks (in/x/dt/out projections, the
 depthwise causal conv as a real ``dwconv`` workload, selective scan on the
 PPUs), RWKV-6 time/channel mix with token-shift and the decay LoRA,
@@ -156,6 +157,11 @@ def build_model_graph(cfg: ModelConfig, *, seq: int = 512, batch: int = 1,
             f"GQA requires n_heads divisible by n_kv_heads >= 1, got "
             f"n_heads={cfg.n_heads} n_kv_heads={cfg.n_kv_heads} "
             f"in {cfg.name}")
+    if any(s.kind == "mla" for s in cfg.layer_pattern) and min(
+            cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim) < 1:
+        raise ValueError(f"latent attention needs every MLA width >= 1 "
+                         f"in {cfg.name}")
 
     d, hd = cfg.d_model, cfg.hd
     prefill = phase == "prefill"
@@ -188,6 +194,25 @@ def build_model_graph(cfg: ModelConfig, *, seq: int = 512, batch: int = 1,
             dict(n=batch, oc=d, ic=d, oh=E, ow=1, kh=3, kw=1))
 
     # -- block emitters ------------------------------------------------------
+    def attn_pair(stage: str, layer: str, op: str, b: int, m: int, n: int,
+                  d_qk: int, d_pv: int, rep: int) -> None:
+        """The score (QK, width ``d_qk``) and context (PV, width ``d_pv``)
+        stages of ``b`` heads×sequences: ``m`` query rows over ``n`` keys."""
+        if fused_attention:
+            # score-stationary fused pair (paper Fig. 10 "Attention"): the
+            # head×batch axis becomes the batched b dim, P = softmax(S) stays
+            # resident between the stages (no HBM round trip for scores)
+            add(stage, layer, f"{op}_scores", "attn_qk",
+                dict(b=b, m=m, n=n, d=d_qk), rep,
+                nt=b * m * n)                              # softmax on PPUs
+            add(stage, layer, f"{op}_context", "attn_pv",
+                dict(b=b, m=m, n=n, d=d_pv), rep)
+        else:
+            add(stage, layer, f"{op}_scores", "gemm",
+                dict(i=m, j=n, k=d_qk), rep * b, nt=m * n)  # softmax on PPUs
+            add(stage, layer, f"{op}_context", "gemm",
+                dict(i=m, j=d_pv, k=n), rep * b)
+
     def attn_block(stage: str, layer: str, spec: BlockSpec, q_len: int,
                    kv_len: int, n_tok: int, rep: int,
                    causal_prefill: bool = True) -> None:
@@ -199,23 +224,40 @@ def build_model_graph(cfg: ModelConfig, *, seq: int = 512, batch: int = 1,
             si, srep = q_len, cfg.n_heads * batch
         else:  # decode: one query row per sequence, batched on i
             si, srep = batch, cfg.n_heads
-        if fused_attention:
-            # score-stationary fused pair (paper Fig. 10 "Attention"): the
-            # head×batch axis becomes the batched b dim, P = softmax(S) stays
-            # resident between the stages (no HBM round trip for scores)
-            add(stage, layer, "attn_scores", "attn_qk",
-                dict(b=srep, m=si, n=eff, d=hd), rep,
-                nt=srep * si * eff)                        # softmax on PPUs
-            add(stage, layer, "attn_context", "attn_pv",
-                dict(b=srep, m=si, n=eff, d=hd), rep)
-        else:
-            add(stage, layer, "attn_scores", "gemm", dict(i=si, j=eff, k=hd),
-                rep * srep, nt=si * eff)                   # softmax on PPUs
-            add(stage, layer, "attn_context", "gemm", dict(i=si, j=hd, k=eff),
-                rep * srep)
+        attn_pair(stage, layer, "attn", srep, si, eff, hd, hd, rep)
         add(stage, layer, "out_proj", "gemm",
             dict(i=n_tok, j=d, k=cfg.n_heads * hd), rep,
             nt=n_tok * d)                                  # residual + norm
+
+    def mla_block(stage: str, layer: str, n_tok: int, rep: int) -> None:
+        """Multi-head latent attention (arXiv:2405.04434 §2.1).  Prefill runs
+        the naive form: the kv latent is up-projected to per-head keys and
+        values.  Decode runs the absorbed form: W_UK folds into the query
+        and W_UV into the output, so every head reads the one cached latent
+        (``kv_lora_rank`` + ``qk_rope_head_dim`` per position) and the step
+        is GEMM-shaped, ``m`` = heads."""
+        H, ql, kl = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        add(stage, layer, "q_a_proj", "gemm", dict(i=n_tok, j=ql, k=d), rep,
+            nt=n_tok * ql)                                 # q latent norm
+        add(stage, layer, "q_b_proj", "gemm",
+            dict(i=n_tok, j=H * (dn + dr), k=ql), rep)
+        add(stage, layer, "kv_a_proj", "gemm", dict(i=n_tok, j=kl + dr, k=d),
+            rep, nt=n_tok * kl)                            # kv latent norm
+        if prefill:
+            add(stage, layer, "kv_b_proj", "gemm",
+                dict(i=n_tok, j=H * (dn + dv), k=kl), rep)
+            attn_pair(stage, layer, "attn", H * batch, S, ctx, dn + dr, dv,
+                      rep)
+        else:
+            add(stage, layer, "absorb_uk", "gemm", dict(i=batch, j=kl, k=dn),
+                rep * H)
+            attn_pair(stage, layer, "attn", batch, H, ctx, kl + dr, kl, rep)
+            add(stage, layer, "absorb_uv", "gemm", dict(i=batch, j=dv, k=kl),
+                rep * H)
+        add(stage, layer, "out_proj", "gemm", dict(i=n_tok, j=d, k=H * dv),
+            rep, nt=n_tok * d)                             # residual + norm
 
     def ffn_block(stage: str, layer: str, spec: BlockSpec, n_tok: int,
                   rep: int) -> None:
@@ -270,6 +312,8 @@ def build_model_graph(cfg: ModelConfig, *, seq: int = 512, batch: int = 1,
         layer, rep = f"dec{i}", cfg.n_periods
         if spec.kind == "attn":
             attn_block("decoder", layer, spec, S, ctx, toks, rep)
+        elif spec.kind == "mla":
+            mla_block("decoder", layer, toks, rep)
         elif spec.kind == "mamba":
             mamba_block("decoder", layer, toks, S if prefill else 1, rep)
         elif spec.kind == "rwkv":
@@ -277,7 +321,7 @@ def build_model_graph(cfg: ModelConfig, *, seq: int = 512, batch: int = 1,
         else:
             raise ValueError(f"unknown block kind {spec.kind!r} "
                              f"in {cfg.name}")
-        if spec.kind in ("attn", "mamba"):  # rwkv carries its channel mix
+        if spec.kind != "rwkv":  # rwkv carries its channel mix
             ffn_block("decoder", layer, spec, toks, rep)
 
     # -- encoder stack + per-decoder-layer cross-attention -------------------
@@ -296,16 +340,7 @@ def build_model_graph(cfg: ModelConfig, *, seq: int = 512, batch: int = 1,
                 dict(i=enc_toks, j=2 * cfg.n_kv_heads * hd, k=d), n_dec)
         si, srep = (S, cfg.n_heads * batch) if prefill else (batch,
                                                             cfg.n_heads)
-        if fused_attention:
-            add("decoder", "xattn", "cross_scores", "attn_qk",
-                dict(b=srep, m=si, n=E, d=hd), n_dec, nt=srep * si * E)
-            add("decoder", "xattn", "cross_context", "attn_pv",
-                dict(b=srep, m=si, n=E, d=hd), n_dec)
-        else:
-            add("decoder", "xattn", "cross_scores", "gemm",
-                dict(i=si, j=E, k=hd), n_dec * srep, nt=si * E)
-            add("decoder", "xattn", "cross_context", "gemm",
-                dict(i=si, j=hd, k=E), n_dec * srep)
+        attn_pair("decoder", "xattn", "cross", srep, si, E, hd, hd, n_dec)
         add("decoder", "xattn", "cross_out_proj", "gemm",
             dict(i=toks, j=d, k=cfg.n_heads * hd), n_dec, nt=toks * d)
 

@@ -4,7 +4,7 @@ Each cell = a jit'd step function + ShapeDtypeStruct inputs + NamedShardings,
 ready to ``.lower().compile()`` — no real allocation anywhere (params come
 from ``jax.eval_shape`` over the initializers).
 
-Assigned shapes (LM family, applied to all 10 archs):
+Assigned shapes (LM family, applied to every arch):
   train_4k     seq 4096   global_batch 256   → train_step
   prefill_32k  seq 32768  global_batch 32    → prefill (forward, no grad)
   decode_32k   seq 32768  global_batch 128   → serve_step (1 token, full KV)

@@ -24,6 +24,7 @@ SHORT = {
     "gemma2_9b": "gemma2-9b",
     "llama4_scout_17b_a16e": "llama4-scout",
     "deepseek_moe_16b": "dsk-moe-16b",
+    "deepseek_v3_671b": "dsk-v3-671b",
     "phi_3_vision_4_2b": "phi3v-4.2b",
     "whisper_base": "whisper-base",
 }

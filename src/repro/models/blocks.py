@@ -1,4 +1,5 @@
-"""Block implementations: GQA attention, dense/MoE FFN, Mamba, RWKV-6.
+"""Block implementations: GQA attention, multi-head latent attention,
+dense/MoE FFN, Mamba, RWKV-6.
 
 Every block provides ``init``, ``fwd`` (full-sequence) and ``step``
 (single-token decode with explicit state).  CPU forward paths share exact
@@ -45,6 +46,20 @@ def _split_heads(x, n, hd):
     return x.reshape(B, T, n, hd)
 
 
+def _causal_attention(cfg: ModelConfig, spec: BlockSpec, qh, kh, vh):
+    """Causal self-attention over (B, H, T, D); streamed over KV chunks on
+    the reference backend at or above ``chunk_threshold`` tokens."""
+    T = qh.shape[2]
+    if cfg.chunk_threshold and T >= cfg.chunk_threshold and KB == "ref":
+        from ..kernels.ref import chunked_attention_ref
+        return chunked_attention_ref(qh, kh, vh, causal=True,
+                                     window=spec.window,
+                                     softcap=cfg.attn_softcap,
+                                     kv_chunk=cfg.attn_kv_chunk)
+    return ops.flash_attention(qh, kh, vh, causal=True, window=spec.window,
+                               softcap=cfg.attn_softcap, backend=KB)
+
+
 def attn_fwd(cfg: ModelConfig, spec: BlockSpec, p, x, positions, mesh=None):
     B, T, d = x.shape
     hd = cfg.hd
@@ -59,15 +74,7 @@ def attn_fwd(cfg: ModelConfig, spec: BlockSpec, p, x, positions, mesh=None):
     # (B, H, T, D) layout for the kernel
     qh, kh, vh = (t.swapaxes(1, 2) for t in (q, k, v))
     qh = with_constraint(qh, mesh, ("batch", "tensor", "none", "none"))
-    if cfg.chunk_threshold and T >= cfg.chunk_threshold and KB == "ref":
-        from ..kernels.ref import chunked_attention_ref
-        o = chunked_attention_ref(qh, kh, vh, causal=True,
-                                  window=spec.window,
-                                  softcap=cfg.attn_softcap,
-                                  kv_chunk=cfg.attn_kv_chunk)
-    else:
-        o = ops.flash_attention(qh, kh, vh, causal=True, window=spec.window,
-                                softcap=cfg.attn_softcap, backend=KB)
+    o = _causal_attention(cfg, spec, qh, kh, vh)
     o = o.swapaxes(1, 2).reshape(B, T, cfg.n_heads * hd)
     o = o @ p["wo"]["w"]
     if cfg.post_block_norm:
@@ -105,6 +112,115 @@ def attn_step(cfg: ModelConfig, spec: BlockSpec, p, x, state, pos, mesh=None):
     if cfg.post_block_norm:
         o = rms_norm(o, p["post_norm"]["scale"], cfg.norm_eps)
     return x + o, {"k": kc, "v": vc}
+
+
+# ===========================================================================
+# multi-head latent attention (DeepSeek-V2/V3, arXiv:2405.04434 §2.1)
+# ===========================================================================
+
+def mla_init(cfg: ModelConfig, key) -> dict:
+    ks = jax.random.split(key, 5)
+    d, H, dt = cfg.d_model, cfg.n_heads, cfg.jdtype
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+    return {
+        "norm": {"scale": jnp.zeros((d,), dt)},
+        "wq_a": _dense(ks[0], d, ql, dt),
+        "q_norm": {"scale": jnp.zeros((ql,), dt)},
+        "wq_b": _dense(ks[1], ql, H * (dn + dr), dt),
+        "wkv_a": _dense(ks[2], d, kl + dr, dt),
+        "kv_norm": {"scale": jnp.zeros((kl,), dt)},
+        "wkv_b": _dense(ks[3], kl, H * (dn + dv), dt),
+        "wo": _dense(ks[4], H * dv, d, dt),
+    }
+
+
+def _mla_q(cfg: ModelConfig, p, h, positions):
+    """Queries through the q latent: (B, T, H, nope) and (B, T, H, rope)
+    with RoPE applied."""
+    B, T, _ = h.shape
+    dn = cfg.qk_nope_head_dim
+    c_q = rms_norm(h @ p["wq_a"]["w"], p["q_norm"]["scale"], cfg.norm_eps)
+    q = (c_q @ p["wq_b"]["w"]).reshape(B, T, cfg.n_heads, -1)
+    return q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta)
+
+
+def _mla_latent(cfg: ModelConfig, p, h, positions):
+    """What the cache holds per position: the normalised kv latent (B, T,
+    kv_lora_rank) and the RoPE key shared by the heads (B, T, rope)."""
+    kl = cfg.kv_lora_rank
+    kv = h @ p["wkv_a"]["w"]
+    c_kv = rms_norm(kv[..., :kl], p["kv_norm"]["scale"], cfg.norm_eps)
+    k_pe = rope(kv[..., None, kl:], positions, cfg.rope_theta)[:, :, 0]
+    return c_kv, k_pe
+
+
+def mla_fwd(cfg: ModelConfig, spec: BlockSpec, p, x, positions, mesh=None):
+    """Naive form: the latent is up-projected to per-head keys and values;
+    scores are nope + rope wide, the context v_head_dim."""
+    B, T, _ = x.shape
+    H, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    dqk = dn + cfg.qk_rope_head_dim
+    h = rms_norm(x, p["norm"]["scale"], cfg.norm_eps)
+    q_nope, q_pe = _mla_q(cfg, p, h, positions)
+    c_kv, k_pe = _mla_latent(cfg, p, h, positions)
+    kv = (c_kv @ p["wkv_b"]["w"]).reshape(B, T, H, dn + dv)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_pe[:, :, None], q_pe.shape)],
+        axis=-1)
+    # the attention kernels take one head width: V is zero-padded to the
+    # score width and the padding sliced off the context
+    v = jnp.pad(kv[..., dn:], ((0, 0),) * 3 + ((0, dqk - dv),))
+    qh, kh, vh = (t.swapaxes(1, 2) for t in (q, k, v))
+    qh = with_constraint(qh, mesh, ("batch", "tensor", "none", "none"))
+    o = _causal_attention(cfg, spec, qh, kh, vh)[..., :dv]
+    o = o.swapaxes(1, 2).reshape(B, T, H * dv)
+    return x + o @ p["wo"]["w"]
+
+
+def mla_init_state(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    return {
+        "c_kv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), cfg.jdtype),
+        "k_rope": jnp.zeros((batch, max_len, cfg.qk_rope_head_dim),
+                            cfg.jdtype),
+    }
+
+
+def mla_prefill_state(cfg: ModelConfig, p, x, positions, max_len: int) -> dict:
+    """The latent cache a prefill of x (B, T, d) leaves for decode, padded
+    to ``max_len`` positions."""
+    h = rms_norm(x, p["norm"]["scale"], cfg.norm_eps)
+    c_kv, k_pe = _mla_latent(cfg, p, h, positions)
+    pad = ((0, 0), (0, max_len - x.shape[1]), (0, 0))
+    return {"c_kv": jnp.pad(c_kv, pad), "k_rope": jnp.pad(k_pe, pad)}
+
+
+def mla_step(cfg: ModelConfig, spec: BlockSpec, p, x, state, pos, mesh=None):
+    """Absorbed form over the latent cache: W_UK folds into the query and
+    W_UV into the context, so all heads attend to one shared latent key
+    (kv latent + RoPE key) and value (kv latent)."""
+    B = x.shape[0]
+    H, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    kl, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    h = rms_norm(x, p["norm"]["scale"], cfg.norm_eps)
+    pvec = jnp.full((B, 1), pos, dtype=jnp.int32)
+    q_nope, q_pe = _mla_q(cfg, p, h, pvec)
+    c_new, k_pe_new = _mla_latent(cfg, p, h, pvec)
+    c_kv = jax.lax.dynamic_update_slice_in_dim(state["c_kv"], c_new, pos,
+                                               axis=1)
+    k_rope = jax.lax.dynamic_update_slice_in_dim(state["k_rope"], k_pe_new,
+                                                 pos, axis=1)
+    w_b = p["wkv_b"]["w"].reshape(kl, H, dn + dv)
+    q_lat = jnp.einsum("bhn,lhn->bhl", q_nope[:, 0], w_b[..., :dn])
+    q = jnp.concatenate([q_lat, q_pe[:, 0]], axis=-1)[:, :, None]
+    k = jnp.concatenate([c_kv, k_rope], axis=-1)[:, None]
+    v = jnp.concatenate([c_kv, jnp.zeros_like(k_rope)], axis=-1)[:, None]
+    o = ops.decode_attention(q, k, v, scale=(dn + dr) ** -0.5, pos=pos,
+                             backend=KB)                # (B, H, 1, kl + dr)
+    o = jnp.einsum("bhl,lhv->bhv", o[:, :, 0, :kl], w_b[..., dn:])
+    o = o.reshape(B, 1, H * dv) @ p["wo"]["w"]
+    return x + o, {"c_kv": c_kv, "k_rope": k_rope}
 
 
 # ===========================================================================
@@ -160,6 +276,10 @@ def moe_init(cfg: ModelConfig, key) -> dict:
             "w_down": make_dense(ks[3], (E, f, d), cfg.jdtype),
         },
     }
+    if cfg.router_score == "sigmoid":
+        # added to the scores for the choice of experts only (DeepSeek-V3's
+        # auxiliary-loss-free balancing)
+        p["router"]["bias"] = jnp.zeros((E,), jnp.float32)
     if cfg.n_shared_experts:
         fs = f * cfg.n_shared_experts
         p["shared"] = {
@@ -216,6 +336,8 @@ def _moe_fwd_shardmap(cfg: ModelConfig, p, x, mesh):
                "w_up": rep(p["experts"]["w_up"]),
                "w_gate": rep(p["experts"]["w_gate"]),
                "w_down": rep(p["experts"]["w_down"])}
+    if "bias" in p["router"]:
+        weights["router_bias"] = rep(p["router"]["bias"])
     if cfg.n_shared_experts:
         weights["s_up"] = rep(p["shared"]["up"]["w"])
         weights["s_gate"] = rep(p["shared"]["gate"]["w"])
@@ -260,18 +382,48 @@ def _moe_fwd_shardmap(cfg: ModelConfig, p, x, mesh):
     return x + y
 
 
+def _route(cfg: ModelConfig, logits, bias):
+    """Top-k expert ids (T, k), their weights and the router's scores.
+
+    ``softmax``: the top k of the softmax, weights renormalised.
+    ``sigmoid`` (DeepSeek-V3): sigmoid scores; ``bias`` shifts them for the
+    choice only; experts are chosen within the ``topk_groups`` best of
+    ``n_expert_groups`` groups, a group scored by its two best; weights are
+    the chosen scores renormalised, times ``routed_scale``."""
+    k = cfg.top_k
+    if cfg.router_score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, eids = jax.lax.top_k(probs, k)
+    else:
+        probs = jax.nn.sigmoid(logits)
+        sel = probs + bias
+        T, E = sel.shape
+        G = cfg.n_expert_groups
+        grouped = sel.reshape(T, G, E // G)
+        best = jax.lax.top_k(grouped, min(2, E // G))[0].sum(-1)   # (T, G)
+        _, gids = jax.lax.top_k(best, cfg.topk_groups)
+        keep = jnp.zeros((T, G), bool).at[
+            jnp.arange(T)[:, None], gids].set(True)
+        sel = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(T, E)
+        _, eids = jax.lax.top_k(sel, k)
+        gate_vals = jnp.take_along_axis(probs, eids, axis=-1)
+    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+    return gate_vals * cfg.routed_scale, eids, probs
+
+
 def _moe_local(cfg: ModelConfig, p, ht, flat: bool = False):
     """Local-token MoE math (no sharding constraints): ht (n_tok, d)."""
     E, k = cfg.n_experts, cfg.top_k
     if flat:
         w = p["_flat"]
-        router_w = w["router"]
+        router_w, router_bias = w["router"], w.get("router_bias")
         w_up, w_gate, w_down = w["w_up"], w["w_gate"], w["w_down"]
         shared = ({"up": {"w": w["s_up"]}, "gate": {"w": w["s_gate"]},
                    "down": {"w": w["s_down"]}}
                   if cfg.n_shared_experts else None)
     else:
         router_w = p["router"]["w"]
+        router_bias = p["router"].get("bias")
         w_up = p["experts"]["w_up"]
         w_gate = p["experts"]["w_gate"]
         w_down = p["experts"]["w_down"]
@@ -279,10 +431,7 @@ def _moe_local(cfg: ModelConfig, p, ht, flat: bool = False):
     n_tok, d = ht.shape
 
     logits = (ht @ router_w).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, eids = jax.lax.top_k(probs, k)               # (T, k)
-    gate_vals = gate_vals / jnp.clip(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, eids, probs = _route(cfg, logits, router_bias)
 
     # load-balancing aux loss (Switch-style)
     me = probs.mean(axis=0)

@@ -1,6 +1,6 @@
 """Shared model machinery: config schema, norms, RoPE, initializers.
 
-One config class covers all 10 assigned architectures; a model is a
+One config class covers every architecture of the zoo; a model is a
 ``layer_pattern`` (the repeating period of block specs — Jamba's 1:7
 Mamba/attention interleave, Gemma-2's local/global alternation, plain
 ``[attn]`` for dense models) times ``n_periods``, executed under
@@ -26,7 +26,7 @@ __all__ = ["BlockSpec", "ModelConfig", "rms_norm", "layer_norm", "rope",
 class BlockSpec:
     """One position in the repeating layer pattern."""
 
-    kind: str = "attn"          # "attn" | "mamba" | "rwkv"
+    kind: str = "attn"          # "attn" | "mla" | "mamba" | "rwkv"
     window: int | None = None   # sliding-window size for local attention
     moe: bool = False           # routed-FFN instead of dense FFN
 
@@ -48,6 +48,16 @@ class ModelConfig:
     final_softcap: float | None = None
     post_block_norm: bool = False   # Gemma-2 sandwich norms
 
+    # multi-head latent attention (kind="mla", DeepSeek-V2/V3): queries pass
+    # through a q_lora_rank latent, keys and values through a kv_lora_rank
+    # latent that is all the cache holds, beside one RoPE key of
+    # qk_rope_head_dim shared by the heads
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # FFN
     d_ff: int = 4096
     activation: str = "silu"        # "silu" (SwiGLU) | "gelu" (GeGLU)
@@ -61,6 +71,12 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     moe_impl: str = "gather"        # "gather" (GSPMD) | "ragged" (shard_map)
+    # DeepSeek-V3 routing: sigmoid scores, experts chosen within the best
+    # topk_groups of n_expert_groups groups, weights scaled by routed_scale
+    router_score: str = "softmax"   # "softmax" | "sigmoid"
+    n_expert_groups: int = 1
+    topk_groups: int = 1
+    routed_scale: float = 1.0
 
     # Mamba
     d_state: int = 16
@@ -126,6 +142,14 @@ class ModelConfig:
             if spec.kind == "attn":
                 n_p = d * hd * (self.n_heads + 2 * self.n_kv_heads) \
                     + self.n_heads * hd * d
+            elif spec.kind == "mla":
+                H, dr = self.n_heads, self.qk_rope_head_dim
+                n_p = d * self.q_lora_rank \
+                    + self.q_lora_rank * H * (self.qk_nope_head_dim + dr) \
+                    + d * (self.kv_lora_rank + dr) \
+                    + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                               + self.v_head_dim) \
+                    + H * self.v_head_dim * d
             elif spec.kind == "mamba":
                 di = self.d_inner
                 n_p = d * 2 * di + di * (self.dtr + 2 * self.d_state) \

@@ -7,8 +7,9 @@ body, keeping HLO size and compile time flat across the zoo.  The period
 body is rematerialized (``jax.checkpoint``) for training.
 
 Decode: ``init_decode_state`` builds per-position state stacks (KV caches /
-SSM states / RWKV states) and ``decode_step`` advances one token, scanning
-over periods with the state slices as scan-carried xs/ys.
+MLA latent caches / SSM states / RWKV states) and ``decode_step`` advances
+one token, scanning over periods with the state slices as scan-carried
+xs/ys.
 """
 
 from __future__ import annotations
@@ -35,6 +36,8 @@ def _block_init(cfg: ModelConfig, spec: BlockSpec, key) -> dict:
     k1, k2 = jax.random.split(key)
     if spec.kind == "attn":
         p = {"core": B.attn_init(cfg, k1)}
+    elif spec.kind == "mla":
+        p = {"core": B.mla_init(cfg, k1)}
     elif spec.kind == "mamba":
         p = {"core": B.mamba_init(cfg, k1)}
     elif spec.kind == "rwkv":
@@ -76,6 +79,8 @@ def init_params(cfg: ModelConfig, key) -> dict:
 def _block_fwd(cfg: ModelConfig, spec: BlockSpec, p, x, positions, mesh):
     if spec.kind == "attn":
         x = B.attn_fwd(cfg, spec, p["core"], x, positions, mesh)
+    elif spec.kind == "mla":
+        x = B.mla_fwd(cfg, spec, p["core"], x, positions, mesh)
     elif spec.kind == "mamba":
         x = B.mamba_fwd(cfg, p["core"], x, mesh)
     elif spec.kind == "rwkv":
@@ -163,6 +168,8 @@ def _pos_state_init(cfg: ModelConfig, spec: BlockSpec, batch: int,
     if spec.kind == "attn":
         cache_len = min(max_len, spec.window) if spec.window else max_len
         return B.attn_init_state(cfg, batch, max_len)
+    if spec.kind == "mla":
+        return B.mla_init_state(cfg, batch, max_len)
     if spec.kind == "mamba":
         return B.mamba_init_state(cfg, batch)
     return B.rwkv_init_state(cfg, batch)
@@ -180,6 +187,8 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int) -> dict:
 def _block_step(cfg, spec, p, x, st, pos, mesh):
     if spec.kind == "attn":
         x, st = B.attn_step(cfg, spec, p["core"], x, st, pos, mesh)
+    elif spec.kind == "mla":
+        x, st = B.mla_step(cfg, spec, p["core"], x, st, pos, mesh)
     elif spec.kind == "mamba":
         x, st = B.mamba_step(cfg, p["core"], x, st, mesh)
     else:

@@ -124,14 +124,16 @@ def _model_config(model: str, reduced: bool):
 def kv_bytes_per_token(model, data_bytes: int = 1,
                        reduced: bool = False) -> int:
     """Per-token KV-cache growth of one request: 2 (K+V) × kv heads ×
-    head_dim × bytes, summed over the attention layers of the pattern.
-    Mamba/RWKV blocks carry constant-size state instead
+    head_dim × bytes per attention layer, and the kv latent + RoPE key
+    (``kv_lora_rank + qk_rope_head_dim``) × bytes per latent-attention
+    layer.  Mamba/RWKV blocks carry constant-size state instead
     (:func:`const_state_bytes`)."""
     cfg = model if not isinstance(model, str) \
         else _model_config(model, reduced)
-    n_attn = cfg.n_periods * sum(1 for s in cfg.layer_pattern
-                                 if s.kind == "attn")
-    return n_attn * 2 * cfg.n_kv_heads * cfg.hd * data_bytes
+    per_layer = {"attn": 2 * cfg.n_kv_heads * cfg.hd,
+                 "mla": cfg.kv_lora_rank + cfg.qk_rope_head_dim}
+    return cfg.n_periods * data_bytes * sum(
+        per_layer.get(s.kind, 0) for s in cfg.layer_pattern)
 
 
 def const_state_bytes(model, data_bytes: int = 1,

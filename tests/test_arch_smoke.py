@@ -118,6 +118,7 @@ def test_param_counts_full_configs():
         "gemma2_9b": (8e9, 11.5e9),
         "llama4_scout_17b_a16e": (90e9, 120e9),
         "deepseek_moe_16b": (14e9, 20e9),
+        "deepseek_v3_671b": (640e9, 700e9),
         "phi_3_vision_4_2b": (3.5e9, 5e9),
     }
     for arch, (lo, hi) in expect.items():
@@ -131,3 +132,5 @@ def test_active_params_moe():
     assert 12e9 < act < 25e9  # ~17B active
     dsk = get_config("deepseek_moe_16b")
     assert 2e9 < dsk.n_active_params() < 5e9  # ~2.8B active
+    v3 = get_config("deepseek_v3_671b")
+    assert 35e9 < v3.n_active_params() < 40e9  # ~37B active

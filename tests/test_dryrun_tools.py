@@ -135,7 +135,12 @@ class TestCellsPlumbing:
 
     def test_all_cells_count(self):
         from repro.launch.cells import all_cells
-        assert len(all_cells()) == 40
+        assert len(all_cells()) == 44
+
+    def test_report_names_every_arch(self):
+        from repro.configs import ARCH_IDS
+        from repro.launch.report import SHORT
+        assert set(SHORT) == set(ARCH_IDS)
 
     def test_mesh_function_shapes(self):
         # make_production_mesh is a function returning the assigned shapes;

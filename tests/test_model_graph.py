@@ -1,5 +1,5 @@
 """Model-graph frontend tests: shape correctness of the lowering for all
-ten assigned configs (both phases), golden dedup counts, and parity with the
+assigned configs (both phases), golden dedup counts, and parity with the
 hand-maintained layer tables the frontend replaced in
 ``benchmarks/nn_workloads.py``."""
 
@@ -81,6 +81,8 @@ class TestGoldenDedup:
         "gemma2_9b":            ((13, 7),  (13, 7)),  # window 4096 > seq 512
         "llama4_scout_17b_a16e": ((8, 8),  (8, 8)),
         "deepseek_moe_16b":     ((8, 8),   (8, 8)),
+        # 61 explicit layers; MLA decode adds the two absorptions
+        "deepseek_v3_671b":     ((608, 13), (669, 14)),
         "phi_3_vision_4_2b":    ((8, 8),   (7, 7)),   # patch stem: prefill only
         "whisper_base":         ((20, 19), (11, 10)),  # encoder: prefill only
     }
@@ -98,6 +100,7 @@ class TestGoldenDedup:
         "gemma2_9b":            ((1, 1), (1, 1)),
         "llama4_scout_17b_a16e": ((1, 1), (1, 1)),
         "deepseek_moe_16b":     ((1, 1), (1, 1)),
+        "deepseek_v3_671b":     ((1, 1), (1, 1)),
         "phi_3_vision_4_2b":    ((1, 1), (1, 1)),
         "whisper_base":         ((3, 3), (2, 2)),
     }
@@ -117,13 +120,19 @@ class TestGoldenDedup:
             got_attn = (sum(1 for k, *_ in rows if k == "attn_qk"),
                         sum(1 for k, *_ in rows if k == "attn_pv"))
             assert got_attn == want_attn, (name, phase)
-            # each qk row pairs with a pv row of identical (dims, repeat):
-            # the contract apply_attention_fusion relies on
-            qk = {(tuple(sorted(d.items())), r) for k, d, r, _ in rows
-                  if k == "attn_qk"}
-            pv = {(tuple(sorted(d.items())), r) for k, d, r, _ in rows
-                  if k == "attn_pv"}
-            assert qk == pv, (name, phase)
+            # each qk row pairs with a pv row over the same score tensor
+            # (b, m, n) and repeat; of identical dims, the contract
+            # apply_attention_fusion relies on, except under latent
+            # attention, whose stages differ in d
+            def pairs(kind, keep_d):
+                return {(tuple(sorted((a, v) for a, v in d.items()
+                                      if keep_d or a != "d")), r)
+                        for k, d, r, _ in rows if k == kind}
+            mla = any(s.kind == "mla" for s in cfg.layer_pattern)
+            assert pairs("attn_qk", False) == pairs("attn_pv", False), \
+                (name, phase)
+            assert (pairs("attn_qk", True) == pairs("attn_pv", True)) \
+                != mla, (name, phase)
 
 
 class TestFamilyFeatures:
@@ -141,6 +150,45 @@ class TestFamilyFeatures:
         up = next(n for n in g.nodes if n.op == "expert_up")
         assert up.repeat == cfg.n_periods * 2 * (6 + 2)  # glu up/gate
         assert up.dims["j"] == cfg.d_ff_expert
+
+    def test_mla_naive_prefill_absorbed_decode(self):
+        """DeepSeek-V3 at 32k: the naive form's rows in prefill, the
+        absorbed form's in decode (arXiv:2405.04434 §2.1), per layer."""
+        cfg = get_config("deepseek_v3_671b")
+        S, d, H = 32768, 7168, 128
+
+        def mla_rows(phase):
+            g = build_model_graph(cfg, seq=S, phase=phase)
+            return [(n.op, n.kind, n.dims, n.repeat) for n in g.nodes
+                    if n.name.startswith("dec0.") and n.op not in
+                    ("ffn_up", "ffn_down")]
+
+        assert mla_rows("prefill") == [
+            ("q_a_proj", "gemm", dict(i=S, j=1536, k=d), 1),
+            ("q_b_proj", "gemm", dict(i=S, j=H * 192, k=1536), 1),
+            ("kv_a_proj", "gemm", dict(i=S, j=512 + 64, k=d), 1),
+            ("kv_b_proj", "gemm", dict(i=S, j=H * 256, k=512), 1),
+            ("attn_scores", "attn_qk", dict(b=H, m=S, n=S, d=192), 1),
+            ("attn_context", "attn_pv", dict(b=H, m=S, n=S, d=128), 1),
+            ("out_proj", "gemm", dict(i=S, j=d, k=H * 128), 1)]
+        assert mla_rows("decode") == [
+            ("q_a_proj", "gemm", dict(i=1, j=1536, k=d), 1),
+            ("q_b_proj", "gemm", dict(i=1, j=H * 192, k=1536), 1),
+            ("kv_a_proj", "gemm", dict(i=1, j=512 + 64, k=d), 1),
+            ("absorb_uk", "gemm", dict(i=1, j=512, k=128), H),
+            ("attn_scores", "attn_qk", dict(b=1, m=H, n=S, d=576), 1),
+            ("attn_context", "attn_pv", dict(b=1, m=H, n=S, d=512), 1),
+            ("absorb_uv", "gemm", dict(i=1, j=128, k=512), H),
+            ("out_proj", "gemm", dict(i=1, j=d, k=H * 128), 1)]
+
+    @pytest.mark.parametrize("phase", PHASES)
+    def test_mla_unfused_lowering_is_the_fallback(self, phase):
+        from repro.frontend import unfuse_attention_rows
+        cfg = get_config("deepseek_v3_671b")
+        fused = lower_model(cfg, seq=4096, phase=phase)
+        plain = lower_model(cfg, seq=4096, phase=phase,
+                            fused_attention=False)
+        assert unfuse_attention_rows(fused) == plain
 
     def test_jamba_ssm_lowers_dwconv(self):
         g = build_model_graph(get_config("jamba_1_5_large_398b"), seq=64)

@@ -148,6 +148,20 @@ class TestSpan:
 # metrics registry
 # ---------------------------------------------------------------------------
 
+def test_lowering_is_spanned():
+    from repro.frontend import lower_model
+
+    enable_tracing()
+    try:
+        lower_model("deepseek_v3_671b", seq=64, phase="decode", reduced=True)
+    finally:
+        disable_tracing()
+    spans = [e for e in drain_events() if e["name"] == "frontend.lower"]
+    assert len(spans) == 1
+    assert spans[0]["args"] == {"model": "deepseek-v3-smoke",
+                                "phase": "decode", "seq": 64}
+
+
 class TestMetrics:
     def test_counter_gauge_histogram(self):
         r = Registry()
@@ -330,6 +344,24 @@ class TestScoringSpans:
         assert c["mapper_batch.jax_rows_padded"] >= \
             c["mapper_batch.jax_candidates"] > 0
         assert "mapper_batch.jax_design_points" not in c
+
+    def test_candidates_counted_per_workload_kind(self):
+        """Every scored candidate row is counted once in total and once
+        under its workload kind (``mapper_batch.jax_candidates.<kind>``)."""
+        _solve_both()  # compiles outside the counted calls
+        METRICS.reset()
+        _solve_both()
+        best_mappings(W.attention_qk(), [({"b": 2, "m": 16, "n": 32,
+                                           "d": 8}, 0.0)],
+                      [SpatialChoice(("m", "n"), (1, 1), "mn")],
+                      HWConfig(n_fus=64), engine="jax")
+        c = METRICS.snapshot()["counters"]
+        kinds = {k: v for k, v in c.items()
+                 if k.startswith("mapper_batch.jax_candidates.")}
+        assert set(kinds) == {"mapper_batch.jax_candidates.gemm",
+                              "mapper_batch.jax_candidates.attention_qk"}
+        assert all(v > 0 for v in kinds.values())
+        assert sum(kinds.values()) == c["mapper_batch.jax_candidates"]
 
     def test_annotations_share_the_profiler_clock(self, tmp_path):
         """Recorded spans are annotations of a profiler session too: same

@@ -185,6 +185,14 @@ class TestHelpers:
                                      if s.kind == "attn")
         assert kv_bytes_per_token(cfg) == n_attn * 2 * cfg.n_kv_heads * cfg.hd
 
+    def test_kv_bytes_per_token_latent_attention(self):
+        from repro.configs import get_config
+        # DeepSeek-V3 caches the 512-wide kv latent and the 64-wide RoPE
+        # key per layer, not 2 x 128 heads x 128
+        cfg = get_config("deepseek_v3_671b")
+        assert kv_bytes_per_token(cfg, data_bytes=1) == 61 * 576
+        assert const_state_bytes(cfg) == 0
+
     def test_recurrent_state_constant(self):
         from repro.configs import get_config
         rwkv = get_config("rwkv6_7b", reduced=True)
