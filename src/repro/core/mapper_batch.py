@@ -28,6 +28,8 @@ across engines (``tests/test_engine_parity.py``).
 
 from __future__ import annotations
 
+from bisect import bisect_right
+from collections.abc import Sequence
 from dataclasses import dataclass
 
 import numpy as np
@@ -54,7 +56,7 @@ class CandidateBatch:
 
     wl: Workload
     spatials: list[SpatialChoice]
-    candidates: list[Candidate]
+    candidates: Sequence[Candidate]
     loop_dim: np.ndarray   # (C, L) int64, -1 = padding slot
     loop_size: np.ndarray  # (C, L) int64
     S: np.ndarray          # (C, D) int64 spatial extent per dim
@@ -74,11 +76,21 @@ def build_batch(
     spatials: list[SpatialChoice],
     hw: HWConfig,
     tile_search: bool = True,
+    memo: dict | None = None,
 ) -> CandidateBatch:
     """Enumerate + lower the candidates of every layer into one batch (span
-    ``mapper_batch.enumerate``)."""
+    ``mapper_batch.enumerate``).
+
+    ``memo`` is a dict owned by one unit of work (a
+    :class:`~repro.dse.cache.MappingCache` holds one).  With it, each
+    layer's rows are built once per spatial choice and FU count and the
+    batch is assembled from them: enumeration dedups only within a spatial
+    choice, so a menu's batch is its choices' rows in menu order, and the
+    result is byte-identical to the batch built without it."""
     with span("mapper_batch.enumerate", cat="mapper", workload=wl.name):
-        return _build_batch(wl, dims_list, spatials, hw, tile_search)
+        if memo is None:
+            return _build_batch(wl, dims_list, spatials, hw, tile_search)
+        return _assemble_batch(wl, dims_list, spatials, hw, tile_search, memo)
 
 
 def _build_batch(wl, dims_list, spatials, hw, tile_search) -> CandidateBatch:
@@ -115,6 +127,93 @@ def _build_batch(wl, dims_list, spatials, hw, tile_search) -> CandidateBatch:
             layer_id[i] = li
             i += 1
         offsets[li + 1] = i
+    return CandidateBatch(wl, list(spatials), cands, loop_dim, loop_size, S,
+                          n_fus, fill, layer_id, offsets)
+
+
+class _MenuCandidates(Sequence):
+    """Candidates of an assembled batch: the memo's entries, each built on
+    a one-choice menu (``spatial_idx`` 0), relabelled on access with their
+    choice's index in the batch's menu."""
+
+    def __init__(self, parts: list[tuple[list[Candidate], int]]):
+        self._parts = [p for p in parts if p[0]]
+        self._starts: list[int] = []
+        n = 0
+        for cands, _ in self._parts:
+            self._starts.append(n)
+            n += len(cands)
+        self._n = n
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, i: int) -> Candidate:
+        if not 0 <= i < self._n:
+            raise IndexError("candidate index out of range")
+        p = bisect_right(self._starts, i) - 1
+        cands, si = self._parts[p]
+        c = cands[i - self._starts[p]]
+        return c if c.spatial_idx == si else Candidate(si, c.facs, c.temporal)
+
+    def __iter__(self):
+        for cands, si in self._parts:
+            for c in cands:
+                yield (c if c.spatial_idx == si
+                       else Candidate(si, c.facs, c.temporal))
+
+
+def _memo_entry(wl, dims, sp, hw, tile_search) -> CandidateBatch:
+    """One layer's rows for one spatial choice, read-only."""
+    e = _build_batch(wl, [dims], [sp], hw, tile_search)
+    for a in (e.loop_dim, e.loop_size, e.S, e.n_fus, e.fill, e.layer_id,
+              e.offsets):
+        a.setflags(write=False)
+    return e
+
+
+def _assemble_batch(wl, dims_list, spatials, hw, tile_search,
+                    memo: dict) -> CandidateBatch:
+    """:func:`_build_batch` from per-(layer, spatial choice) memo entries:
+    layers in query order, spatial choices in menu order."""
+    parts: list[tuple[CandidateBatch, int]] = []
+    per_layer = np.zeros(len(dims_list), dtype=np.int64)
+    hits = 0
+    for li, dims in enumerate(dims_list):
+        dkey = tuple(sorted(dims.items()))
+        for si, sp in enumerate(spatials):
+            key = (wl.name, sp, hw.n_fus, dkey, tile_search)
+            e = memo.get(key)
+            if e is None:
+                e = memo[key] = _memo_entry(wl, dims, sp, hw, tile_search)
+            else:
+                hits += 1
+            parts.append((e, si))
+            per_layer[li] += e.n_candidates
+    METRICS.counter("mapper_batch.enum_memo_lookups").inc(len(parts))
+    METRICS.counter("mapper_batch.enum_memo_hits").inc(hits)
+
+    C = int(per_layer.sum())
+    L = max((e.loop_dim.shape[1] for e, _ in parts), default=0)
+    loop_dim = np.full((C, L), -1, dtype=np.int64)
+    loop_size = np.ones((C, L), dtype=np.int64)
+    S = np.ones((C, len(wl.iter_dims)), dtype=np.int64)
+    n_fus = np.empty(C, dtype=np.int64)
+    fill = np.empty(C, dtype=np.float64)
+    lo = 0
+    for e, _ in parts:
+        hi = lo + e.n_candidates
+        el = e.loop_dim.shape[1]
+        loop_dim[lo:hi, :el] = e.loop_dim
+        loop_size[lo:hi, :el] = e.loop_size
+        S[lo:hi] = e.S
+        n_fus[lo:hi] = e.n_fus
+        fill[lo:hi] = e.fill
+        lo = hi
+    layer_id = np.repeat(np.arange(len(dims_list), dtype=np.int64), per_layer)
+    offsets = np.zeros(len(dims_list) + 1, dtype=np.int64)
+    np.cumsum(per_layer, out=offsets[1:])
+    cands = _MenuCandidates([(e.candidates, si) for e, si in parts])
     return CandidateBatch(wl, list(spatials), cands, loop_dim, loop_size, S,
                           n_fus, fill, layer_id, offsets)
 
@@ -190,6 +289,7 @@ def best_mappings(
     objective: str = "cycles",
     tile_search: bool = True,
     engine: str = "numpy",
+    memo: dict | None = None,
 ) -> list[Mapping]:
     """Best mapping for every ``(dims, ppu_elements)`` query of one workload.
 
@@ -202,10 +302,12 @@ def best_mappings(
     the stable-lexsort selection runs on the host either way, and the
     per-layer winners are re-scored through the NumPy kernel so the returned
     :class:`Mapping` is byte-identical to the ``engine="numpy"`` result.
+    ``memo`` is passed to :func:`build_batch`.
     """
     dims_list = [q[0] for q in queries]
     ppu_list = [float(q[1]) for q in queries]
-    batch = build_batch(wl, dims_list, spatials, hw, tile_search=tile_search)
+    batch = build_batch(wl, dims_list, spatials, hw, tile_search=tile_search,
+                        memo=memo)
     r = evaluate_batch(batch, hw, dims_list, ppu_list,
                        data_nodes_per_tensor=data_nodes_per_tensor,
                        engine=engine)

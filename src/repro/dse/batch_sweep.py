@@ -126,7 +126,8 @@ def _prefill_tile(evaluator: Evaluator, tile: list[DesignPoint],
         # per-query subsetting would fragment the (D, C) dispatch shape
         # for no win — the batch is one compiled call either way
         min_c, min_l = buckets.get(wl.name, (1, 4))
-        cand = build_batch(wl, [q[0] for q in queries], sps, hw_list[0])
+        cand = build_batch(wl, [q[0] for q in queries], sps, hw_list[0],
+                           memo=cache.enum_memo)
         mappings = best_mappings_design(
             wl, queries, sps, [hw_list[di] for di in need_d],
             data_nodes_per_tensor_list=[dn] * len(need_d),

@@ -155,6 +155,10 @@ class MappingCache:
         self.hits = 0
         self.misses = 0
         self._dirty = False
+        # the unit of work's candidate rows per (kind, spatial choice, FU
+        # count, dims, tile_search), for build_batch(memo=...): memory
+        # only, never saved nor counted in hits/misses
+        self.enum_memo: dict = {}
         if autoload and self.path and os.path.exists(self.path):
             self.load()
 
@@ -367,7 +371,8 @@ class MappingCache:
                 solved = best_mappings(
                     wl, [queries[i] for i in miss], spatials, hw,
                     data_nodes_per_tensor=data_nodes_per_tensor,
-                    objective=objective, engine=engine)
+                    objective=objective, engine=engine,
+                    memo=self.enum_memo)
             for i, m in zip(miss, solved):
                 self.put(keys[i], {"perf": m.perf.as_dict(),
                                    "spatial": m.spatial.name,

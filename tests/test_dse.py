@@ -326,6 +326,44 @@ class TestEvolveSearch:
         assert r.n_designs <= 10
 
 
+def _memo_less(ev):
+    ev.cache.enum_memo = None  # build_batch(memo=None): today's lowering
+    return ev
+
+
+class TestEnumMemoFrontier:
+    """The mapping cache's enumeration memo changes no answer: the same
+    visit order, scorecards and frontier as without it."""
+
+    def test_evolve_search_unchanged(self, tmp_path):
+        from repro.obs import METRICS
+
+        h0 = METRICS.snapshot()["counters"].get(
+            "mapper_batch.enum_memo_hits", 0)
+        a = evolve_search(SPACES["small"], _mini_evaluator(tmp_path / "m"),
+                          budget=18, seed=5)
+        h1 = METRICS.snapshot()["counters"].get(
+            "mapper_batch.enum_memo_hits", 0)
+        b = evolve_search(SPACES["small"],
+                          _memo_less(_mini_evaluator(tmp_path / "n")),
+                          budget=18, seed=5)
+        assert h1 > h0
+        assert a.extra["visited"] == b.extra["visited"]
+        assert _dump(a.evals) == _dump(b.evals)
+        assert _dump(a.frontier) == _dump(b.frontier)
+
+    @needs_jax
+    def test_batch_sweep_unchanged(self, tmp_path):
+        ev = _mini_evaluator(tmp_path / "m")
+        a = batch_sweep(SPACES["tiny"], ev, d_tile=2)
+        assert ev.cache.enum_memo
+        b = batch_sweep(SPACES["tiny"],
+                        _memo_less(_mini_evaluator(tmp_path / "n")),
+                        d_tile=2)
+        assert _dump(a.evals) == _dump(b.evals)
+        assert _dump(a.frontier) == _dump(b.frontier)
+
+
 class TestPlanTiles:
     def test_partition_and_grouping(self):
         pts = list(SPACES["small"].enumerate())

@@ -175,6 +175,198 @@ class TestEnumeration:
             [(c.spatial_idx, c.facs, c.temporal) for c in explicit]
 
 
+# every kind a dataflow set carries a menu for, at dims that need padding
+# (primes and odd extents that no FU factor divides)
+_MEMO_WLS = {w.name: w for w in (W.gemm(), W.conv2d(), W.depthwise_conv2d(),
+                                 W.attention_qk(), W.attention_pv())}
+_MEMO_DIMS = {
+    "gemm": [dict(i=7, j=130, k=56), dict(i=512, j=64, k=3)],
+    "conv2d": [dict(n=1, oc=64, ic=3, oh=7, ow=13, kh=3, kw=3)],
+    "dwconv2d": [dict(n=1, c=32, oh=14, ow=13, kh=3, kw=3)],
+    "attention_qk": [dict(b=2, m=1, n=130, d=64), dict(b=4, m=16, n=17, d=7)],
+    "attention_pv": [dict(b=2, m=7, n=33, d=64)],
+}
+_MEMO_FUS = (64, 96)
+_BATCH_ARRAYS = ("loop_dim", "loop_size", "S", "n_fus", "fill", "layer_id",
+                 "offsets")
+
+
+def _menus(dataflow_set):
+    from repro.dse.space import DATAFLOW_SETS
+    return {k: list(v) for k, v in DATAFLOW_SETS[dataflow_set].items()}
+
+
+def _assert_same_batch(ref, got, ctx=""):
+    for f in _BATCH_ARRAYS:
+        a, b = getattr(ref, f), getattr(got, f)
+        assert a.dtype == b.dtype and a.shape == b.shape, (f, ctx)
+        assert a.tobytes() == b.tobytes(), (f, ctx)
+    assert got.n_candidates == ref.n_candidates, ctx
+    assert list(got.candidates) == list(ref.candidates), ctx
+    assert got.spatials == ref.spatials, ctx
+
+
+def _memo_counts():
+    from repro.obs import METRICS
+    c = METRICS.snapshot()["counters"]
+    return (c.get("mapper_batch.enum_memo_lookups", 0),
+            c.get("mapper_batch.enum_memo_hits", 0),
+            c.get("mapper.candidates_enumerated", 0))
+
+
+class TestEnumMemo:
+    """``build_batch(memo=...)`` assembles a batch from per-(layer, spatial
+    choice) rows; it must equal the batch built without it, byte for
+    byte, in every array, dtype and candidate."""
+
+    @pytest.mark.parametrize("tile_search", [True, False])
+    @pytest.mark.parametrize("dataflow_set",
+                             ["os", "ws", "switch", "attention_fused"])
+    def test_byte_identical_to_memo_less(self, dataflow_set, tile_search):
+        memo: dict = {}
+        for n_fus in _MEMO_FUS:
+            hw = HWConfig(n_fus=n_fus)
+            for kind, sps in _menus(dataflow_set).items():
+                wl, dims_list = _MEMO_WLS[kind], _MEMO_DIMS[kind]
+                ref = build_batch(wl, dims_list, sps, hw,
+                                  tile_search=tile_search)
+                for _ in range(2):  # cold, then every entry a hit
+                    got = build_batch(wl, dims_list, sps, hw,
+                                      tile_search=tile_search, memo=memo)
+                    _assert_same_batch(ref, got, (kind, n_fus))
+        assert memo
+
+    @pytest.mark.parametrize("order", [("ws", "switch"),
+                                       ("attention_fused", "os")])
+    def test_warm_order_across_menus(self, order):
+        """Rows built under one menu serve another that shares a spatial
+        choice, relabelled with the new menu's ``spatial_idx``."""
+        memo: dict = {}
+        for dataflow_set in order:
+            for n_fus in _MEMO_FUS:
+                hw = HWConfig(n_fus=n_fus)
+                for kind, sps in _menus(dataflow_set).items():
+                    wl, dims_list = _MEMO_WLS[kind], _MEMO_DIMS[kind]
+                    _assert_same_batch(
+                        build_batch(wl, dims_list, sps, hw),
+                        build_batch(wl, dims_list, sps, hw, memo=memo),
+                        (dataflow_set, kind, n_fus))
+
+    @pytest.mark.parametrize("objective", ["cycles", "energy", "edp"])
+    def test_winners_match_memo_less(self, objective):
+        """Each winner keeps the menu's own spatial choice and dataflow."""
+        memo: dict = {}
+        for dataflow_set in ("ws", "switch", "attention_fused"):
+            for kind, sps in _menus(dataflow_set).items():
+                wl = _MEMO_WLS[kind]
+                queries = [(d, 4096.0) for d in _MEMO_DIMS[kind]]
+                for hw in (HWConfig(n_fus=64, buffer_bytes=64 * 1024),
+                           HWConfig(n_fus=64, buffer_bytes=512 * 1024,
+                                    dram_gbps=64.0)):
+                    ref = best_mappings(wl, queries, sps, hw,
+                                        objective=objective)
+                    got = best_mappings(wl, queries, sps, hw,
+                                        objective=objective, memo=memo)
+                    for ms, mb in zip(ref, got):
+                        _assert_same_mapping(ms, mb, (dataflow_set, kind))
+                        assert mb.spatial is sps[sps.index(ms.spatial)]
+
+    def test_entries_read_only_and_keyed_on_what_enumeration_reads(self):
+        memo: dict = {}
+        wl, dims = W.gemm(), dict(i=7, j=130, k=56)
+        sps = _menus("switch")["gemm"]
+        build_batch(wl, [dims], sps, HWConfig(n_fus=64), memo=memo)
+        # buffer and bandwidth do not enter the key, the FU count does
+        build_batch(wl, [dims], sps,
+                    HWConfig(n_fus=64, buffer_bytes=1 << 20,
+                             dram_gbps=128.0), memo=memo)
+        assert len(memo) == 2
+        build_batch(wl, [dims], sps, HWConfig(n_fus=128), memo=memo)
+        build_batch(wl, [dims], sps, HWConfig(n_fus=64), tile_search=False,
+                    memo=memo)
+        assert len(memo) == 6
+        for e in memo.values():
+            for f in _BATCH_ARRAYS:
+                with pytest.raises(ValueError):
+                    getattr(e, f)[...] = 0
+        # an assembled batch owns its arrays
+        got = build_batch(wl, [dims], sps, HWConfig(n_fus=64), memo=memo)
+        assert all(getattr(got, f).flags.writeable for f in _BATCH_ARRAYS)
+
+    def test_counters(self):
+        """One lookup per (layer, spatial choice); the enumeration counter
+        counts only the enumerations actually run."""
+        memo: dict = {}
+        wl = W.gemm()
+        dims_list = _MEMO_DIMS["gemm"]
+        sps = _menus("switch")["gemm"]
+        hw = HWConfig(n_fus=64)
+        l0, h0, e0 = _memo_counts()
+        cold = build_batch(wl, dims_list, sps, hw, memo=memo)
+        l1, h1, e1 = _memo_counts()
+        assert (l1 - l0, h1 - h0) == (4, 0)
+        assert e1 - e0 == cold.n_candidates
+        build_batch(wl, dims_list, sps, hw, memo=memo)
+        # "ws" shares the "jk" rows of both layers with "switch"
+        build_batch(wl, dims_list, _menus("ws")["gemm"], hw, memo=memo)
+        l2, h2, e2 = _memo_counts()
+        assert (l2 - l1, h2 - h1, e2 - e1) == (6, 6, 0)
+        # no memo: no lookups, every candidate enumerated
+        build_batch(wl, dims_list, sps, hw)
+        l3, h3, e3 = _memo_counts()
+        assert (l3, h3, e3 - e2) == (l2, h2, cold.n_candidates)
+
+    def test_mapping_caches_share_nothing(self):
+        from repro.dse.cache import MappingCache
+
+        wl, sps = W.gemm(), _menus("switch")["gemm"]
+        queries = [(d, 0.0) for d in _MEMO_DIMS["gemm"]]
+        a, b = MappingCache(), MappingCache()
+        assert a.enum_memo is not b.enum_memo
+        a.best_mapping_perfs(wl, queries, sps, HWConfig(n_fus=64))
+        assert a.enum_memo and not b.enum_memo
+        l0, h0, _ = _memo_counts()
+        b.best_mapping_perfs(wl, queries, sps, HWConfig(n_fus=64))
+        l1, h1, _ = _memo_counts()
+        assert (l1 - l0, h1 - h0) == (4, 0)
+        assert b.enum_memo.keys() == a.enum_memo.keys()
+        assert not any(b.enum_memo[k] is a.enum_memo[k] for k in a.enum_memo)
+        # memory only: nothing of it in the stats nor the saved store
+        assert (a.hits, a.misses) == (0, 2)
+        assert len(a.snapshot()) == 2
+
+    def test_prefilter_then_full_evaluation_hits(self):
+        """The evolve prefilter shares its parent's mapping cache, so the
+        full evaluation of a child that differs in buffer alone finds its
+        rows already built, and scores as it would without the memo."""
+        from dataclasses import replace
+
+        from repro.configs import get_config
+        from repro.dse import DesignPoint, Evaluator, MappingCache
+        from repro.dse.evaluate import lower_config
+
+        zoo = {n: lower_config(get_config(n, reduced=True), seq=32)
+               for n in ("gemma_7b", "glm4_9b")}
+        pre_name = min(zoo, key=lambda n: (len(zoo[n]), n))
+        p = DesignPoint(n_fus=64, buffer_kb=128, dataflow_set="switch")
+        q = replace(p, buffer_kb=256)
+
+        ev = Evaluator(zoo=zoo, cache=MappingCache())
+        pre_ev = Evaluator(zoo={pre_name: zoo[pre_name]}, cache=ev.cache,
+                           objective=ev.objective, engine=ev.engine)
+        pre_ev.evaluate(p)
+        built = len(ev.cache.enum_memo)
+        l0, h0, _ = _memo_counts()
+        got = ev.evaluate(q)
+        l1, h1, _ = _memo_counts()
+        assert built and h1 - h0 >= built and l1 - l0 > h1 - h0
+
+        cold = MappingCache()
+        cold.enum_memo = None
+        ref = Evaluator(zoo=zoo, cache=cold).evaluate(q)
+        assert got.as_dict() == ref.as_dict()
+
+
 class TestKernelsAgainstScalar:
     def test_layer_perf_is_batch_of_one(self):
         """The scalar API wraps the batched kernels: a hand-built dataflow
