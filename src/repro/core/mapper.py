@@ -14,7 +14,7 @@ candidates, which is what let the default flip on.
 Candidate enumeration (:func:`enumerate_candidates`) is shared between two
 evaluation engines:
 
-``engine="numpy"`` (default; alias ``"batch"``)
+``engine="numpy"`` (default)
     the NumPy-vectorized engine in :mod:`repro.core.mapper_batch` — the
     whole candidate set is scored in one broadcasted perf-kernel pass.
 ``engine="jax"``
@@ -230,10 +230,10 @@ def best_mapping(
     data_nodes_per_tensor: dict[str, int] | None = None,
     ppu_elements: float = 0.0,
     objective: str = "cycles",  # "cycles" | "energy" | "edp"
-    engine: str = "numpy",      # "numpy" | "batch" (alias) | "jax" | "scalar"
+    engine: str = "numpy",      # "numpy" | "jax" | "scalar"
     tile_search: bool = True,
 ) -> Mapping:
-    if engine in ("numpy", "batch", "jax"):
+    if engine in ("numpy", "jax"):
         from .mapper_batch import best_mappings
         return best_mappings(
             wl, [(dims, ppu_elements)], spatials, hw,
@@ -241,7 +241,7 @@ def best_mapping(
             objective=objective, tile_search=tile_search, engine=engine)[0]
     if engine != "scalar":
         raise ValueError(f"unknown engine {engine!r} "
-                         f"(expected 'numpy', 'jax', 'scalar' or 'batch')")
+                         f"(expected 'numpy', 'jax' or 'scalar')")
 
     best: Mapping | None = None
     best_key: tuple | None = None

@@ -19,11 +19,16 @@ asserted by the parity suite in ``tests/test_mapper_batch.py``.
 
 ``engine="jax"`` swaps the scoring pass for the AOT-compiled XLA kernel in
 :mod:`repro.core.perf_model_jax` (one fused dispatch for the whole batch).
-Selection **stays on the host**: the same stable lexsort runs over the
-JAX-scored arrays, and the per-layer winners are then re-scored through the
-NumPy kernel, so the reported :class:`LayerPerf` — and everything downstream
-of it (mapping caches, scorecards, Pareto frontiers) — is byte-identical
-across engines (``tests/test_engine_parity.py``).
+There is one solver for it: :func:`best_mappings_design` scores a batch
+against D designs in one ``(design, candidate)`` dispatch, and
+``best_mappings(engine="jax")`` is its one-design case.  Both build one
+query table per solve (:func:`_query_table`) and share one selection and
+re-score (:func:`_mappings`).  Selection **stays on the host**: the same
+stable lexsort runs over the JAX-scored arrays, and the per-layer winners
+are then re-scored through the NumPy kernel, so the reported
+:class:`LayerPerf` — and everything downstream of it (mapping caches,
+scorecards, Pareto frontiers) — is byte-identical across engines
+(``tests/test_engine_parity.py``).
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ from __future__ import annotations
 from bisect import bisect_right
 from collections.abc import Sequence
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
@@ -218,6 +224,45 @@ def _assemble_batch(wl, dims_list, spatials, hw, tile_search,
                           n_fus, fill, layer_id, offsets)
 
 
+class _Queries(NamedTuple):
+    """The query table of one solve: what a layer and a design add to the
+    candidate rows."""
+
+    true: np.ndarray  # (n_layers, D) int64 true sizes, NO_TRUE_SIZE if none
+    ppu: np.ndarray   # (n_layers,) float64 PPU elements
+    dn: np.ndarray    # (n_designs, T) int64 data-node row of each design
+
+
+def _query_table(wl: Workload, dims_list: list[dict[str, int]],
+                 ppu_list: list[float], hw_list: list[HWConfig],
+                 dnt_list: Sequence[dict[str, int] | None]) -> _Queries:
+    """Build the :class:`_Queries` of a solve, once for all its designs."""
+    true = np.full((len(dims_list), len(wl.iter_dims)), NO_TRUE_SIZE,
+                   dtype=np.int64)
+    for li, dims in enumerate(dims_list):
+        for i, d in enumerate(wl.iter_dims):
+            if d in dims:
+                true[li, i] = dims[d]
+    # a tensor without a count reads one bank per FU: mapper candidates
+    # always span exactly hw.n_fus FUs, so min(dn, n_fus) == n_fus either way
+    dn = np.array([[(dnt or {}).get(t.name, hw.n_fus) for t in wl.tensors]
+                   for hw, dnt in zip(hw_list, dnt_list)], dtype=np.int64)
+    return _Queries(true, np.asarray(ppu_list, dtype=np.float64), dn)
+
+
+def _score(kernel, batch: CandidateBatch, hw: HWConfig, q: _Queries,
+           di: int = 0, rows=slice(None)) -> dict[str, np.ndarray]:
+    """``kernel`` over the candidate ``rows`` of ``batch`` for ``hw``, the
+    design of data-node row ``q.dn[di]``."""
+    lid = batch.layer_id[rows]
+    dn = q.dn[di:di + 1]
+    return kernel(batch.wl, hw, batch.loop_dim[rows], batch.loop_size[rows],
+                  batch.S[rows], n_fus=batch.n_fus[rows],
+                  fill=batch.fill[rows], true_sizes=q.true[lid],
+                  data_nodes=np.broadcast_to(dn, (len(lid), dn.shape[1])),
+                  ppu_elements=q.ppu[lid])
+
+
 def evaluate_batch(
     batch: CandidateBatch,
     hw: HWConfig,
@@ -228,44 +273,26 @@ def evaluate_batch(
 ) -> dict[str, np.ndarray]:
     """Score every candidate row: one broadcasted perf-kernel pass.
 
-    ``engine="numpy"`` (alias ``"batch"``) runs the broadcasted NumPy
-    kernels; ``engine="jax"`` runs the jitted XLA port — integer-derived
-    outputs are bit-identical, ``energy_pj`` within
+    ``engine="numpy"`` runs the broadcasted NumPy kernels;
+    ``engine="jax"`` runs the jitted XLA port — integer-derived outputs
+    are bit-identical, ``energy_pj`` within
     :data:`repro.core.perf_model_jax.ENERGY_RTOL` (see that module for the
     tolerance policy), returned in a read-only mapping that copies each
     output but ``cycles`` and ``energy_pj`` from the device on first read."""
-    wl = batch.wl
-    D = len(wl.iter_dims)
-    n_layers = len(dims_list)
-    true = np.full((n_layers, D), NO_TRUE_SIZE, dtype=np.int64)
-    for li, dims in enumerate(dims_list):
-        for i, d in enumerate(wl.iter_dims):
-            if d in dims:
-                true[li, i] = dims[d]
-    if data_nodes_per_tensor is None:
-        # scalar default is one bank read per FU; mapper candidates always
-        # span exactly hw.n_fus FUs, so min(dn, n_fus) == n_fus either way
-        dn_row = [hw.n_fus for _ in wl.tensors]
-    else:
-        dn_row = [data_nodes_per_tensor.get(t.name, hw.n_fus)
-                  for t in wl.tensors]
-    dn = np.array([dn_row], dtype=np.int64)
-    ppu = np.asarray(ppu_list, dtype=np.float64)
-    lid = batch.layer_id
-    if engine in ("numpy", "batch"):
-        kernel = perf_kernel
-    elif engine == "jax":
+    q = _query_table(batch.wl, dims_list, ppu_list, [hw],
+                     [data_nodes_per_tensor])
+    return _score(_kernel(engine), batch, hw, q)
+
+
+def _kernel(engine: str):
+    """The scoring kernel of ``engine``; the JAX one is looked up on each
+    call, so a wrapper installed on its module sees every dispatch."""
+    if engine == "numpy":
+        return perf_kernel
+    if engine == "jax":
         from .perf_model_jax import perf_kernel_jax
-        kernel = perf_kernel_jax
-    else:
-        raise ValueError(f"unknown engine {engine!r} "
-                         f"(expected 'numpy', 'jax' or 'batch')")
-    return kernel(wl, hw, batch.loop_dim, batch.loop_size, batch.S,
-                  n_fus=batch.n_fus, fill=batch.fill,
-                  true_sizes=true[lid],
-                  data_nodes=np.broadcast_to(
-                      dn, (batch.n_candidates, dn.shape[1])),
-                  ppu_elements=ppu[lid])
+        return perf_kernel_jax
+    raise ValueError(f"unknown engine {engine!r} (expected 'numpy' or 'jax')")
 
 
 def _argbest(cycles: np.ndarray, energy: np.ndarray, objective: str) -> int:
@@ -298,54 +325,27 @@ def best_mappings(
     concatenated and scored in a single kernel pass; argmin runs per layer
     slice.  Only winners become :class:`Dataflow`/:class:`Mapping` objects.
 
-    With ``engine="jax"`` the candidate scores come from one XLA dispatch;
-    the stable-lexsort selection runs on the host either way, and the
-    per-layer winners are re-scored through the NumPy kernel so the returned
+    ``engine="numpy"`` reports the NumPy scores of the winners directly.
+    ``engine="jax"`` is the one-design case of
+    :func:`best_mappings_design`: the candidate scores come from one XLA
+    dispatch (:func:`~repro.core.perf_model_jax.perf_kernel_jax`), the
+    stable-lexsort selection runs on the host, and the per-layer winners
+    are re-scored through the NumPy kernel, so the returned
     :class:`Mapping` is byte-identical to the ``engine="numpy"`` result.
     ``memo`` is passed to :func:`build_batch`.
     """
+    kernel = _kernel(engine)
     dims_list = [q[0] for q in queries]
     ppu_list = [float(q[1]) for q in queries]
     batch = build_batch(wl, dims_list, spatials, hw, tile_search=tile_search,
                         memo=memo)
-    r = evaluate_batch(batch, hw, dims_list, ppu_list,
-                       data_nodes_per_tensor=data_nodes_per_tensor,
-                       engine=engine)
+    q = _query_table(wl, dims_list, ppu_list, [hw], [data_nodes_per_tensor])
+    r = _score(kernel, batch, hw, q)
     METRICS.counter("mapper.batch_solves").inc()
     METRICS.counter("mapper.layers_solved").inc(len(queries))
     METRICS.counter("mapper.candidates_scored").inc(batch.n_candidates)
-    with span("mapper_batch.select", cat="mapper"):
-        winners = _winners(batch, r["cycles"], r["energy_pj"], len(queries),
-                           objective)
-    with span("mapper_batch.rescore", cat="mapper"):
-        rows = winners
-        if engine == "jax":
-            # report NumPy-exact numbers for the winners (a batch of
-            # n_layers rows — negligible next to the candidate fan-out):
-            # float-ulp drift in the XLA energies can never leak into
-            # caches or frontiers
-            r = _rescore_rows(batch, r, winners, hw, dims_list, ppu_list,
-                              data_nodes_per_tensor)
-            rows = list(range(len(queries)))  # rescored row li = winner li
-        out: list[Mapping] = []
-        for li, w in enumerate(winners):
-            cand = batch.candidates[w]
-            out.append(Mapping(materialize(wl, cand, spatials),
-                               LayerPerf.from_kernel(r, rows[li]),
-                               spatials[cand.spatial_idx]))
-    return out
-
-
-def _winners(batch: CandidateBatch, cycles: np.ndarray, energy: np.ndarray,
-             n_queries: int, objective: str) -> list[int]:
-    """Row of the objective-best candidate of every query's slice."""
-    winners: list[int] = []
-    for li in range(n_queries):
-        lo, hi = int(batch.offsets[li]), int(batch.offsets[li + 1])
-        assert hi > lo, "no feasible mapping"
-        winners.append(lo + _argbest(cycles[lo:hi], energy[lo:hi],
-                                     objective))
-    return winners
+    return _mappings(batch, r["cycles"][None], r["energy_pj"][None], [hw], q,
+                     objective, reported=r if engine == "numpy" else None)[0]
 
 
 def best_mappings_design(
@@ -363,16 +363,17 @@ def best_mappings_design(
 ) -> list[list[Mapping]]:
     """Best mappings for every query against **D design points** at once.
 
-    The design-axis twin of :func:`best_mappings`: one candidate batch is
-    enumerated (all designs must share ``n_fus`` — candidate enumeration
-    depends on the design only through the FU count, asserted here) and one
-    ``(design, candidate)`` XLA dispatch scores it against every design's
-    runtime HW parameters (:func:`perf_kernel_jax_design`).  Selection and
-    reporting follow the PR-8 engine contract per design: host-side stable
-    lexsort over the JAX scores, then the per-layer winners are re-scored
-    through the NumPy kernel, so ``result[d]`` is byte-identical to
-    ``best_mappings(..., hw_list[d], engine="jax")`` — and therefore to the
-    NumPy engine.  Returns ``result[d][q]`` (D × len(queries) mappings).
+    One candidate batch is enumerated (all designs must share ``n_fus`` —
+    candidate enumeration depends on the design only through the FU count,
+    asserted here) and one ``(design, candidate)`` XLA dispatch scores it
+    against every design's runtime HW parameters
+    (:func:`perf_kernel_jax_design`).  Selection and reporting follow the
+    engine contract per design: host-side stable lexsort over the JAX
+    scores, then the per-layer winners are re-scored through the NumPy
+    kernel, so ``result[d]`` is byte-identical to
+    ``best_mappings(..., hw_list[d], engine="jax")``, its one-design case —
+    and therefore to the NumPy engine.  Returns ``result[d][q]``
+    (D × len(queries) mappings).
 
     ``min_c``/``min_l``/``min_d`` forward bucket floors to the kernel so a
     tiled sweep can pin one compiled shape across tiles.
@@ -387,77 +388,61 @@ def best_mappings_design(
     if batch is None:
         batch = build_batch(wl, dims_list, spatials, hw_list[0],
                             tile_search=tile_search)
-
-    D = len(wl.iter_dims)
-    true = np.full((len(queries), D), NO_TRUE_SIZE, dtype=np.int64)
-    for li, dims in enumerate(dims_list):
-        for i, d in enumerate(wl.iter_dims):
-            if d in dims:
-                true[li, i] = dims[d]
-    dn_rows = []
-    for di, hw in enumerate(hw_list):
-        dnt = (data_nodes_per_tensor_list[di]
-               if data_nodes_per_tensor_list else None)
-        if dnt is None:
-            dn_rows.append([hw.n_fus for _ in wl.tensors])
-        else:
-            dn_rows.append([dnt.get(t.name, hw.n_fus) for t in wl.tensors])
-    ppu = np.asarray(ppu_list, dtype=np.float64)
+    q = _query_table(wl, dims_list, ppu_list, hw_list,
+                     data_nodes_per_tensor_list or [None] * len(hw_list))
     lid = batch.layer_id
-
     r = perf_kernel_jax_design(
         wl, hw_list, batch.loop_dim, batch.loop_size, batch.S,
-        n_fus=batch.n_fus, fill=batch.fill, true_sizes=true[lid],
-        data_nodes=np.asarray(dn_rows, dtype=np.int64),
-        ppu_elements=ppu[lid], min_c=min_c, min_l=min_l, min_d=min_d)
+        n_fus=batch.n_fus, fill=batch.fill, true_sizes=q.true[lid],
+        data_nodes=q.dn, ppu_elements=q.ppu[lid], min_c=min_c, min_l=min_l,
+        min_d=min_d)
     METRICS.counter("mapper.design_batch_solves").inc()
     METRICS.counter("mapper.layers_solved").inc(len(hw_list) * len(queries))
     METRICS.counter("mapper.candidates_scored").inc(
         len(hw_list) * batch.n_candidates)
+    return _mappings(batch, r["cycles"], r["energy_pj"], hw_list, q,
+                     objective)
 
+
+def _mappings(batch: CandidateBatch, cycles: np.ndarray, energy: np.ndarray,
+              hw_list: list[HWConfig], q: _Queries, objective: str,
+              reported: dict | None = None) -> list[list[Mapping]]:
+    """Every design's per-layer winners as :class:`Mapping` lists, from the
+    ``(D, C)`` scores of ``batch`` (span ``mapper_batch.select``).
+
+    The winners' reported numbers (span ``mapper_batch.rescore``) come from
+    the NumPy kernel: from ``reported``, the one design's NumPy scores of
+    the whole batch, where given; else from a re-score of the winner rows,
+    so float-ulp drift in device energies never leaks into caches or
+    frontiers."""
+    n_layers = len(q.true)
     with span("mapper_batch.select", cat="mapper"):
-        winners = [_winners(batch, r["cycles"][di], r["energy_pj"][di],
-                            len(queries), objective)
-                   for di in range(len(hw_list))]
+        winners = [_winners(batch, cycles[di], energy[di], n_layers,
+                            objective) for di in range(len(hw_list))]
+    sps = batch.spatials
     out: list[list[Mapping]] = []
     with span("mapper_batch.rescore", cat="mapper"):
-        for di, hw in enumerate(hw_list):
-            dnt = (data_nodes_per_tensor_list[di]
-                   if data_nodes_per_tensor_list else None)
-            rd = _rescore_rows(batch, r, winners[di], hw, dims_list,
-                               ppu_list, dnt)
-            out.append([Mapping(materialize(wl, batch.candidates[w],
-                                            spatials),
-                                LayerPerf.from_kernel(rd, li),
-                                spatials[batch.candidates[w].spatial_idx])
-                        for li, w in enumerate(winners[di])])
+        for di, (hw, rows) in enumerate(zip(hw_list, winners)):
+            if reported is None:  # row i of the re-score is winner i
+                r = _score(perf_kernel, batch, hw, q, di, rows)
+                at = range(n_layers)
+            else:
+                r, at = reported, rows
+            cands = [batch.candidates[w] for w in rows]
+            out.append([Mapping(materialize(batch.wl, c, sps),
+                                LayerPerf.from_kernel(r, i),
+                                sps[c.spatial_idx])
+                        for c, i in zip(cands, at)])
     return out
 
 
-def _rescore_rows(batch: CandidateBatch, r: dict, rows: list[int],
-                  hw: HWConfig, dims_list, ppu_list,
-                  data_nodes_per_tensor) -> dict[str, np.ndarray]:
-    """NumPy ``perf_kernel`` over a row subset of ``batch`` (the per-layer
-    winners of a JAX-scored pass), keeping the candidate row encoding."""
-    wl = batch.wl
-    idx = np.asarray(rows, dtype=np.int64)
-    D = len(wl.iter_dims)
-    n_layers = len(dims_list)
-    true = np.full((n_layers, D), NO_TRUE_SIZE, dtype=np.int64)
-    for li, dims in enumerate(dims_list):
-        for i, d in enumerate(wl.iter_dims):
-            if d in dims:
-                true[li, i] = dims[d]
-    if data_nodes_per_tensor is None:
-        dn_row = [hw.n_fus for _ in wl.tensors]
-    else:
-        dn_row = [data_nodes_per_tensor.get(t.name, hw.n_fus)
-                  for t in wl.tensors]
-    dn = np.broadcast_to(np.array([dn_row], dtype=np.int64),
-                         (len(rows), len(dn_row)))
-    ppu = np.asarray(ppu_list, dtype=np.float64)
-    lid = batch.layer_id[idx]
-    return perf_kernel(wl, hw, batch.loop_dim[idx], batch.loop_size[idx],
-                       batch.S[idx], n_fus=batch.n_fus[idx],
-                       fill=batch.fill[idx], true_sizes=true[lid],
-                       data_nodes=dn, ppu_elements=ppu[lid])
+def _winners(batch: CandidateBatch, cycles: np.ndarray, energy: np.ndarray,
+             n_queries: int, objective: str) -> list[int]:
+    """Row of the objective-best candidate of every query's slice."""
+    winners: list[int] = []
+    for li in range(n_queries):
+        lo, hi = int(batch.offsets[li]), int(batch.offsets[li + 1])
+        assert hi > lo, "no feasible mapping"
+        winners.append(lo + _argbest(cycles[lo:hi], energy[lo:hi],
+                                     objective))
+    return winners

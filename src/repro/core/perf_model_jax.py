@@ -5,10 +5,17 @@ per Python call; at the ROADMAP's 10⁵–10⁶-design sweep scale the remaining
 cost is the per-batch NumPy interpreter overhead and the lost opportunity to
 fuse the whole extents → footprint → traffic → perf chain into one compiled
 dispatch.  This module re-expresses the same math as a **per-candidate JAX
-function vmapped over the candidate axis** and AOT-compiles it with
-``jax.jit``, so an entire design×mapping×layer tensor scores in a single
-XLA dispatch — the affine-representation-is-just-arrays property the LEGO
-front end is built on.
+function vmapped over the candidate axis and then over the design axis**
+and AOT-compiles it with ``jax.jit``, so an entire design×mapping×layer
+tensor scores in a single XLA dispatch — the
+affine-representation-is-just-arrays property the LEGO front end is built
+on.
+
+There is one program and one dispatch path: :func:`perf_kernel_jax_design`
+scores ``D`` designs × ``C`` candidates, and :func:`perf_kernel_jax`
+(one design, ``(C,)`` results) is its ``D = 1`` case, so the sweeps and
+the per-design searches run the same compiled ``(design, candidate)``
+kernel.
 
 Contract with the NumPy engine (the differential-testing harness in
 ``tests/test_engine_parity.py`` pins all of this):
@@ -20,11 +27,10 @@ Contract with the NumPy engine (the differential-testing harness in
 * ``energy_pj`` may differ by float-associativity noise (XLA is free to
   contract multiply-adds into FMAs), bounded by :data:`ENERGY_RTOL`;
 * selection therefore never trusts JAX floats for the *reported* numbers:
-  :func:`repro.core.mapper_batch.best_mappings` uses the JAX scores only to
-  order candidates (host-side stable lexsort, identical code path) and
-  re-scores the per-layer winners through the NumPy kernel, so mapping
-  caches, scorecards and Pareto frontiers are byte-identical across
-  engines.
+  :mod:`repro.core.mapper_batch` uses the JAX scores only to order
+  candidates (host-side stable lexsort, identical code path) and re-scores
+  the per-layer winners through the NumPy kernel, so mapping caches,
+  scorecards and Pareto frontiers are byte-identical across engines.
 
 JAX is imported lazily and only on first use: DSE worker processes stay
 NumPy-only unless ``engine="jax"`` is actually requested, and environments
@@ -53,8 +59,7 @@ __all__ = ["jax_available", "perf_kernel_jax", "perf_kernel_jax_design",
            "use_compile_cache", "device_record", "ENGINES"]
 
 # the engines a mapping query can be solved with ("numpy" is the batched
-# default; "batch" is its historical alias; "scalar" is the reference
-# candidate-at-a-time oracle)
+# default; "scalar" is the reference candidate-at-a-time oracle)
 ENGINES = ("numpy", "jax", "scalar")
 
 # tolerance policy for float energies (everything else is exact): XLA may
@@ -229,51 +234,6 @@ def _workload_kernel(jax, wl: Workload, L: int):
         L, len(wl.iter_dims))
 
 
-def _compiled_kernel(jax, wl: Workload, C: int, L: int):
-    """AOT-compiled vmapped kernel for (workload structure, padded shapes).
-
-    HW parameters are runtime arguments, so one compilation serves every
-    design point of a sweep; the cache key is only the workload name and
-    the bucketed batch shape.  The compile-vs-execute split is observable:
-    ``mapper_batch.jax_compiles`` + the ``mapper_batch.jax_compile`` span
-    cover compilation, ``mapper_batch.jax_dispatches`` the warm dispatches.
-    """
-    D = len(wl.iter_dims)
-    T = len(wl.tensors)
-    key = (wl.name, C, L)
-    fn = _COMPILED.get(key)
-    if fn is not None:
-        return fn
-
-    kernel = _workload_kernel(jax, wl, L)
-    # vmap over the candidate axis; HW scalars/vectors broadcast (None)
-    batched = jax.vmap(kernel,
-                       in_axes=(0, 0, 0, 0, 0, 0, None, 0,
-                                None, None, None, None, None, None, None,
-                                None, None, None))
-
-    sds = jax.ShapeDtypeStruct
-    f64 = np.dtype(np.float64)
-    shapes = (
-        sds((C, L), np.int64), sds((C, L), np.int64), sds((C, D), np.int64),
-        sds((C,), np.int64), sds((C,), f64), sds((C, D), np.int64),
-        sds((T,), np.int64), sds((C,), f64),
-        sds((T,), f64), sds((T,), f64), sds((), f64), sds((), f64),
-        sds((), f64), sds((), f64), sds((), f64), sds((), f64), sds((), f64),
-        sds((), f64),
-    )
-    t0 = time.perf_counter()
-    with span("mapper_batch.jax_compile", cat="mapper", workload=wl.name,
-              candidates=C, loops=L):
-        with jax.enable_x64(True):
-            fn = jax.jit(batched).lower(*shapes).compile()
-    METRICS.counter("mapper_batch.jax_compiles").inc()
-    METRICS.histogram("mapper_batch.jax_compile_s").observe(
-        time.perf_counter() - t0)
-    _COMPILED[key] = fn
-    return fn
-
-
 def _pad_loops(loop_dim: np.ndarray, loop_size: np.ndarray, Cp: int,
                Lp: int) -> tuple[np.ndarray, np.ndarray]:
     """The ``(Cp, Lp)`` loop rows: padding slots are inert (dim -1, size 1)
@@ -377,66 +337,8 @@ def _pad_rows(a: np.ndarray, C: int) -> np.ndarray:
     return np.ascontiguousarray(a)
 
 
-def perf_kernel_jax(
-    wl: Workload,
-    hw: HWConfig,
-    loop_dim: np.ndarray,
-    loop_size: np.ndarray,
-    S: np.ndarray,
-    n_fus: np.ndarray,
-    fill: np.ndarray,
-    true_sizes: np.ndarray,
-    data_nodes: np.ndarray,
-    ppu_elements: np.ndarray,
-) -> Mapping[str, np.ndarray]:
-    """Drop-in JAX replacement for :func:`repro.core.perf_model.perf_kernel`.
-
-    Same candidate row encoding, same result keys; the whole batch scores in
-    one XLA dispatch.  ``data_nodes`` rows must be identical across the
-    batch (the mapper-batch invariant: one data-node vector per query set) —
-    asserted, because the vmapped kernel broadcasts a single ``(T,)`` row.
-    Results come back as a read-only mapping of host NumPy arrays sliced to
-    the true batch size; outputs other than :data:`EAGER_OUTPUTS` are
-    copied from the device on first read (:class:`KernelOutputs`).
-    """
-    jax = _require_jax()
-    C, L = loop_size.shape
-    if C == 0:
-        from .perf_model import perf_kernel
-        return perf_kernel(wl, hw, loop_dim, loop_size, S, n_fus, fill,
-                           true_sizes, data_nodes, ppu_elements)
-    assert (data_nodes == data_nodes[0]).all(), \
-        "engine='jax' expects one shared data-node row per batch"
-    Cp, Lp = _bucket_c(C), _bucket_l(L)
-    fn = _compiled_kernel(jax, wl, Cp, Lp)
-
-    with span("mapper_batch.pack", cat="mapper"):
-        tensors = list(wl.tensors)
-        budget = np.full(len(tensors), hw.buffer_bytes / len(tensors),
-                         dtype=np.float64)
-        db = np.array([hw.acc_bytes if t.role == "output" else hw.data_bytes
-                       for t in tensors], dtype=np.float64)
-        args = (
-            *_pad_loops(loop_dim, loop_size, Cp, Lp),
-            _pad_rows(S, Cp), _pad_rows(n_fus, Cp),
-            _pad_rows(fill.astype(np.float64), Cp),
-            _pad_rows(true_sizes, Cp),
-            np.asarray(data_nodes[0], dtype=np.int64),
-            _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
-            budget, db,
-            np.float64(hw.bytes_per_cycle), np.float64(max(1, hw.n_ppus)),
-            np.float64(hw.e_mac_pj), np.float64(hw.e_reg_pj_per_byte),
-            np.float64(hw.e_ppu_pj),
-            np.float64(hw.static_mw / hw.freq_ghz * 1e-3),  # mW·ns = pJ
-            np.float64(sram_read_pj_per_byte(hw.buffer_bytes)),
-            np.float64(hw.data_bytes),
-        )
-    return _execute(jax, fn, args, C, Cp, (slice(C),), workload=wl.name,
-                    candidates=C)
-
-
 # ---------------------------------------------------------------------------
-# design axis: one dispatch scores D design points × C candidates
+# one program: a dispatch scores D design points × C candidates
 # ---------------------------------------------------------------------------
 
 def design_kernel_program(jax, wl: Workload, Dp: int, C: int, L: int,
@@ -459,6 +361,7 @@ def design_kernel_program(jax, wl: Workload, Dp: int, C: int, L: int,
     D = len(wl.iter_dims)
     T = len(wl.tensors)
     kernel = _workload_kernel(jax, wl, L)
+    # inner vmap over the candidate axis; HW scalars/vectors broadcast (None)
     per_design = jax.vmap(kernel,
                           in_axes=(0, 0, 0, 0, 0, 0, None, 0,
                                    None, None, None, None, None, None, None,
@@ -487,9 +390,12 @@ def design_kernel_program(jax, wl: Workload, Dp: int, C: int, L: int,
 def _compiled_design_kernel(jax, wl: Workload, Dp: int, C: int, L: int):
     """AOT-compiled :func:`design_kernel_program` for the default device.
 
-    The cache key is ``(workload, "design", Dp, Cp, Lp)``; HW parameters are
-    runtime arguments exactly as in :func:`_compiled_kernel`, so one compile
-    serves every tile of a sweep that reuses the same bucketed shape.
+    HW parameters are runtime arguments, so one compile serves every design
+    point that lands on the same bucketed shape; the cache key is
+    ``(workload, "design", Dp, Cp, Lp)``.  The compile-vs-execute split is
+    observable: ``mapper_batch.jax_compiles`` + the
+    ``mapper_batch.jax_compile`` span cover compilation,
+    ``mapper_batch.jax_dispatches`` the warm dispatches.
     """
     key = (wl.name, "design", Dp, C, L)
     fn = _COMPILED.get(key)
@@ -531,47 +437,23 @@ def _hw_rows(hw_list: list[HWConfig], tensors) -> tuple[np.ndarray, ...]:
     )
 
 
-def perf_kernel_jax_design(
-    wl: Workload,
-    hw_list: list[HWConfig],
-    loop_dim: np.ndarray,
-    loop_size: np.ndarray,
-    S: np.ndarray,
-    n_fus: np.ndarray,
-    fill: np.ndarray,
-    true_sizes: np.ndarray,
-    data_nodes: np.ndarray,
-    ppu_elements: np.ndarray,
-    min_c: int = 1,
-    min_l: int = 4,
-    min_d: int = 1,
-) -> Mapping[str, np.ndarray]:
-    """Score one candidate batch against **D designs** in one XLA dispatch.
-
-    Candidate arrays are the shared ``(C, …)`` row encoding of
-    :func:`perf_kernel_jax` (all designs must enumerate the identical
-    candidate set — callers group designs by ``n_fus``); ``data_nodes`` is
-    one ``(D, T)`` row per design.  Returns ``(D, C)``-shaped host arrays,
-    in a mapping that copies each output but :data:`EAGER_OUTPUTS` on first
-    read, as :func:`perf_kernel_jax` does.
-
-    ``min_c`` / ``min_l`` / ``min_d`` are bucket floors: a sweep
-    orchestrator passes its running per-workload maxima so every tile lands
-    on the same padded shape and the first compile serves all tiles.
-    """
+def _dispatch(wl: Workload, hw_list: list[HWConfig], loop_dim, loop_size, S,
+              n_fus, fill, true_sizes, data_nodes, ppu_elements, rows: tuple,
+              min_c: int, min_l: int, min_d: int) -> Mapping[str, np.ndarray]:
+    """Score the candidate rows against every design of ``hw_list`` in one
+    dispatch of the ``(design, candidate)`` program: bucket the shapes,
+    compile or fetch the program, pack the rows, run it; the outputs are
+    indexed by ``rows`` (the real rows of the padded ``(Dp, Cp)``
+    outputs)."""
     jax = _require_jax()
     C, L = loop_size.shape
     Dn = len(hw_list)
-    assert Dn >= 1 and data_nodes.shape[0] == Dn
-    if C == 0:
+    if C == 0:  # nothing to score: the NumPy kernel's empty outputs
         from .perf_model import perf_kernel
-        return {k: np.stack([v for v in vs])
-                for k, vs in _transpose_dicts(
-                    [perf_kernel(wl, hw, loop_dim, loop_size, S, n_fus, fill,
-                                 true_sizes, np.empty((0, data_nodes.shape[1]),
-                                                      dtype=np.int64),
-                                 ppu_elements)
-                     for hw in hw_list]).items()}
+        empty = perf_kernel(wl, hw_list[0], loop_dim, loop_size, S, n_fus,
+                            fill, true_sizes, data_nodes[:0], ppu_elements)
+        return {k: np.empty((Dn, 0), dtype=v.dtype)[rows]
+                for k, v in empty.items()}
     Cp = _bucket_c(max(C, min_c))
     Lp = _bucket_l(max(L, min_l))
     Dp = _bucket_c(max(Dn, min_d))
@@ -590,13 +472,70 @@ def perf_kernel_jax_design(
             _pad_rows(np.asarray(ppu_elements, dtype=np.float64), Cp),
             *hw_rows,
         )
-    return _execute(jax, fn, args, Dn * C, Dp * Cp, (slice(Dn), slice(C)),
-                    workload=wl.name, designs=Dn, candidates=C)
+    return _execute(jax, fn, args, Dn * C, Dp * Cp, rows, workload=wl.name,
+                    designs=Dn, candidates=C)
 
 
-def _transpose_dicts(dicts: list[dict]) -> dict[str, list]:
-    out: dict[str, list] = {}
-    for d in dicts:
-        for k, v in d.items():
-            out.setdefault(k, []).append(v)
-    return out
+def perf_kernel_jax_design(
+    wl: Workload,
+    hw_list: list[HWConfig],
+    loop_dim: np.ndarray,
+    loop_size: np.ndarray,
+    S: np.ndarray,
+    n_fus: np.ndarray,
+    fill: np.ndarray,
+    true_sizes: np.ndarray,
+    data_nodes: np.ndarray,
+    ppu_elements: np.ndarray,
+    min_c: int = 1,
+    min_l: int = 4,
+    min_d: int = 1,
+) -> Mapping[str, np.ndarray]:
+    """Score one candidate batch against **D designs** in one XLA dispatch.
+
+    Candidate arrays are the shared ``(C, …)`` row encoding of
+    :func:`repro.core.perf_model.perf_kernel` (all designs must enumerate
+    the identical candidate set — callers group designs by ``n_fus``);
+    ``data_nodes`` is one ``(D, T)`` row per design.  Returns
+    ``(D, C)``-shaped host arrays, in a read-only mapping that copies each
+    output but :data:`EAGER_OUTPUTS` from the device on first read
+    (:class:`KernelOutputs`).
+
+    ``min_c`` / ``min_l`` / ``min_d`` are bucket floors: a sweep
+    orchestrator passes its running per-workload maxima so every tile lands
+    on the same padded shape and the first compile serves all tiles.
+    """
+    C = loop_size.shape[0]
+    Dn = len(hw_list)
+    assert Dn >= 1 and data_nodes.shape[0] == Dn
+    return _dispatch(wl, hw_list, loop_dim, loop_size, S, n_fus, fill,
+                     true_sizes, data_nodes, ppu_elements,
+                     (slice(Dn), slice(C)), min_c, min_l, min_d)
+
+
+def perf_kernel_jax(
+    wl: Workload,
+    hw: HWConfig,
+    loop_dim: np.ndarray,
+    loop_size: np.ndarray,
+    S: np.ndarray,
+    n_fus: np.ndarray,
+    fill: np.ndarray,
+    true_sizes: np.ndarray,
+    data_nodes: np.ndarray,
+    ppu_elements: np.ndarray,
+) -> Mapping[str, np.ndarray]:
+    """Drop-in JAX replacement for :func:`repro.core.perf_model.perf_kernel`:
+    the one-design case of :func:`perf_kernel_jax_design`.
+
+    Same candidate row encoding, same result keys, ``(C,)`` arrays.
+    ``data_nodes`` rows must be identical across the batch (the
+    mapper-batch invariant: one data-node vector per query set) — asserted,
+    because the program takes one ``(T,)`` row per design.
+    """
+    assert (data_nodes == data_nodes[:1]).all(), \
+        "engine='jax' expects one shared data-node row per batch"
+    return _dispatch(wl, [hw], loop_dim, loop_size, S, n_fus, fill,
+                     true_sizes, data_nodes[:1], ppu_elements,
+                     (0, slice(loop_size.shape[0])), min_c=1, min_l=4,
+                     min_d=1)

@@ -36,7 +36,7 @@ import tempfile
 import time
 from contextlib import contextmanager
 
-from repro.core.mapper import Mapping, SpatialChoice, best_mapping
+from repro.core.mapper import SpatialChoice, best_mapping
 from repro.core.mapper_batch import best_mappings
 from repro.core.perf_model import HWConfig, LayerPerf
 from repro.core.workload import Workload
@@ -310,27 +310,11 @@ class MappingCache:
                           ppu_elements: float = 0.0,
                           objective: str = "cycles",
                           engine: str = "numpy") -> LayerPerf:
-        """Cached ``best_mapping`` returning the winning :class:`LayerPerf`.
-
-        The entry also records the winning spatial-dataflow name, retrievable
-        via :meth:`lookup_spatial`.  ``engine`` selects how misses are
-        solved; it is deliberately **not** part of :func:`mapping_key` —
-        every engine returns byte-identical winners, so an entry computed
-        by one engine is a valid hit for all of them.
-        """
-        key = mapping_key(wl, dims, spatials, hw, data_nodes_per_tensor,
-                          ppu_elements, objective)
-        e = self.get(key)
-        if e is not None:
-            return LayerPerf.from_dict(e["perf"])
-        m: Mapping = best_mapping(
-            wl, dims, spatials, hw,
-            data_nodes_per_tensor=data_nodes_per_tensor,
-            ppu_elements=ppu_elements, objective=objective, engine=engine)
-        self.put(key, {"perf": m.perf.as_dict(),
-                       "spatial": m.spatial.name,
-                       "dataflow": m.dataflow.name})
-        return m.perf
+        """Cached ``best_mapping`` returning the winning :class:`LayerPerf`:
+        :meth:`best_mapping_perfs` of one query."""
+        return self.best_mapping_perfs(
+            wl, [(dims, ppu_elements)], spatials, hw, data_nodes_per_tensor,
+            objective, engine)[0]
 
     def best_mapping_perfs(self, wl: Workload,
                            queries: list[tuple[dict, float]],
@@ -338,14 +322,17 @@ class MappingCache:
                            data_nodes_per_tensor: dict[str, int] | None = None,
                            objective: str = "cycles",
                            engine: str = "numpy") -> list[LayerPerf]:
-        """Batched :meth:`best_mapping_perf` over ``(dims, ppu_elements)``
-        queries sharing one workload/spatial-menu/data-node shape.
+        """Cached best mappings of ``(dims, ppu_elements)`` queries sharing
+        one workload/spatial-menu/data-node shape, as :class:`LayerPerf`.
 
         Cache hits are answered immediately; all misses are solved in a
         single vectorized :func:`~repro.core.mapper_batch.best_mappings`
         pass — this is the DSE evaluator's per-(design, workload-kind)
-        front door.  ``engine`` selects the miss solver only: keys carry no
-        engine field, so caches are interchangeable across engines
+        front door.  Each entry also records the winning spatial-dataflow
+        name, retrievable via :meth:`lookup_spatial`.  ``engine`` selects
+        the miss solver only; it is deliberately **not** part of
+        :func:`mapping_key` — every engine returns byte-identical winners,
+        so an entry computed by one engine is a valid hit for all of them
         (``engine="scalar"`` falls back to per-query reference solves).
         """
         with span("mapper_cache.keys", cat="mapper"):

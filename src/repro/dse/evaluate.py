@@ -200,7 +200,7 @@ class Evaluator:
             raise ValueError(f"unknown baseline {baseline!r}")
         self.baseline = baseline
         from repro.core.perf_model_jax import ENGINES
-        if engine not in ENGINES and engine != "batch":
+        if engine not in ENGINES:
             raise ValueError(f"unknown engine {engine!r} "
                              f"(expected one of {ENGINES})")
         # miss-solver selection only: mapping-cache keys carry no engine

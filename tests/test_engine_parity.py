@@ -319,6 +319,76 @@ class TestDesignAxisParity:
             best_mappings_design(wl, q, sps, [])
 
 
+@needs_jax
+class TestOneScoringPath:
+    """Per-design scoring is the one-design case of the design-batched
+    program: the same outputs, byte for byte, and only design programs are
+    ever compiled."""
+
+    _CASES = {"gemm": [({"i": 56, "j": 16, "k": 130}, 0.0),
+                       ({"i": 16, "j": 512, "k": 7}, 4096.0)],
+              "attention_qk": [({"b": 2, "m": 16, "n": 56, "d": 130},
+                                0.0)],
+              "conv2d": [({"n": 1, "oc": 16, "ic": 3, "oh": 7, "ow": 56,
+                           "kh": 3, "kw": 3}, 4096.0)]}
+
+    @pytest.mark.parametrize("name", ["gemm", "attention_qk", "conv2d"],
+                             ids=["gemm", "attn_qk", "conv"])
+    def test_per_design_is_row_zero_of_the_design_kernel(self, name):
+        from repro.core.mapper_batch import best_mappings_design
+        from repro.core.perf_model_jax import (perf_kernel_jax,
+                                               perf_kernel_jax_design)
+        wl, sps, queries = _WLS[name], _SP_MENU[name], self._CASES[name]
+        dn = {t.name: 16 for t in wl.tensors}
+        for kb in (64, 128, 512):
+            hw = HWConfig(n_fus=64, buffer_bytes=kb * 1024)
+            batch = build_batch(wl, [q[0] for q in queries], sps, hw)
+            true = np.array([[q[0][d] for d in wl.iter_dims]
+                             for q in queries])[batch.layer_id]
+            ppu = np.array([q[1] for q in queries])[batch.layer_id]
+            rows = (batch.loop_dim, batch.loop_size, batch.S, batch.n_fus,
+                    batch.fill, true)
+            dn_rows = np.full((batch.n_candidates, len(wl.tensors)), 16)
+            one = perf_kernel_jax(wl, hw, *rows, dn_rows, ppu)
+            both = perf_kernel_jax_design(wl, [hw], *rows, dn_rows[:1], ppu)
+            assert list(one) == list(both) and len(one) == 8
+            for k in one:
+                assert one[k].dtype == both[k].dtype, (name, kb, k)
+                assert one[k].shape == (batch.n_candidates,), (name, kb, k)
+                assert one[k].tobytes() == both[k][0].tobytes(), \
+                    (name, kb, k)
+            solo = best_mappings(wl, queries, sps, hw,
+                                 data_nodes_per_tensor=dn, engine="jax")
+            [stacked] = best_mappings_design(
+                wl, queries, sps, [hw], data_nodes_per_tensor_list=[dn])
+            assert [m.perf.as_dict() for m in solo] == \
+                [m.perf.as_dict() for m in stacked]
+
+    def test_per_design_solve_compiles_only_design_programs(self):
+        from repro.core import perf_model_jax as pmj
+        from repro.obs import METRICS
+
+        wl, sps = _WLS["gemm"], _SP_MENU["gemm"]
+        queries = self._CASES["gemm"]
+        hw = HWConfig(n_fus=64)
+
+        def compiles():
+            return METRICS.snapshot()["counters"].get(
+                "mapper_batch.jax_compiles", 0)
+
+        pmj.clear_compile_cache()
+        c0 = compiles()
+        cold = best_mappings(wl, queries, sps, hw, engine="jax")
+        c1 = compiles()
+        assert c1 - c0 == len(pmj._COMPILED) == 1
+        assert all(key[1] == "design" and key[2] == 1
+                   for key in pmj._COMPILED)
+        warm = best_mappings(wl, queries, sps, hw, engine="jax")
+        assert compiles() == c1, "a repeated solve must not compile"
+        for ma, mb in zip(cold, warm):
+            _assert_same_mapping(ma, mb, "warm")
+
+
 class TestCacheCrossEngine:
     """dse/cache.py engine invariance: keys carry no engine field, so a
     cache populated by one engine must serve every other engine."""
@@ -388,20 +458,14 @@ class TestEngineValidation:
         from repro.dse import Evaluator
         wl, hw = _WLS["gemm"], HWConfig(n_fus=64)
         dims = {"i": 16, "j": 16, "k": 16}
-        with pytest.raises(ValueError, match="engine"):
-            best_mapping(wl, dims, _SP_MENU["gemm"], hw, engine="fortran")
         batch = build_batch(wl, [dims], _SP_MENU["gemm"], hw)
-        with pytest.raises(ValueError, match="engine"):
-            evaluate_batch(batch, hw, [dims], [0.0], engine="fortran")
-        with pytest.raises(ValueError, match="engine"):
-            Evaluator(zoo={}, engine="fortran")
-
-    def test_batch_alias_still_accepted(self):
-        wl, hw = _WLS["gemm"], HWConfig(n_fus=64)
-        dims = {"i": 56, "j": 16, "k": 130}
-        ma = best_mapping(wl, dims, _SP_MENU["gemm"], hw, engine="batch")
-        mb = best_mapping(wl, dims, _SP_MENU["gemm"], hw, engine="numpy")
-        _assert_same_mapping(ma, mb, "batch alias")
+        for engine in ("fortran", "batch"):
+            with pytest.raises(ValueError, match="engine"):
+                best_mapping(wl, dims, _SP_MENU["gemm"], hw, engine=engine)
+            with pytest.raises(ValueError, match="engine"):
+                evaluate_batch(batch, hw, [dims], [0.0], engine=engine)
+            with pytest.raises(ValueError, match="engine"):
+                Evaluator(zoo={}, engine=engine)
 
     def test_scalar_engine_through_cache_front_door(self):
         from repro.dse.cache import MappingCache

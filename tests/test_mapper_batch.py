@@ -76,7 +76,7 @@ class TestScalarBatchParity:
                               engine="scalar")
             mb = best_mapping(wl, dims, sps, hw, data_nodes_per_tensor=dn,
                               ppu_elements=ppu, objective=obj,
-                              engine="batch")
+                              engine="numpy")
             _assert_same_mapping(ms, mb, (wl.name, dims, obj))
 
     def test_tile_search_parity_and_no_regression(self):
@@ -90,7 +90,7 @@ class TestScalarBatchParity:
                               engine="scalar", tile_search=True)
             mb = best_mapping(wl, dims, sps, hw, data_nodes_per_tensor=dn,
                               ppu_elements=ppu, objective="cycles",
-                              engine="batch", tile_search=True)
+                              engine="numpy", tile_search=True)
             _assert_same_mapping(ms, mb, (wl.name, dims, "tile"))
             base = best_mapping(wl, dims, sps, hw, data_nodes_per_tensor=dn,
                                 ppu_elements=ppu, objective="cycles",
@@ -122,7 +122,7 @@ class TestScalarBatchParity:
         ms = best_mapping(wl, dims, GEMM_SP, hw, objective=objective,
                           engine="scalar")
         mb = best_mapping(wl, dims, GEMM_SP, hw, objective=objective,
-                          engine="batch")
+                          engine="numpy")
         _assert_same_mapping(ms, mb, (dims, objective))
 
 
